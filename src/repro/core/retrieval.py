@@ -18,14 +18,12 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.kernels.retrieval_topk.ref import retrieval_topk_ref
-from repro.runtime.compat import shard_map
 
 
 def local_topk(q_emb, corpus_shard, m, use_pallas: bool = False):
     if use_pallas:
         from repro.kernels.retrieval_topk.kernel import retrieval_topk_pallas
 
-        # interpret mode is auto-selected from the backend inside the kernel
         return retrieval_topk_pallas(q_emb, corpus_shard, m)
     return retrieval_topk_ref(q_emb, corpus_shard, m)
 
@@ -70,7 +68,7 @@ def federated_topk(
         return top_s, top_g, top_p
 
     other_axes = [a for a in mesh.axis_names if a != provider_axis]
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(), P(provider_axis, None), P()),

@@ -24,6 +24,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import on_backend
+
 NEG_INF = -1e30
 
 
@@ -83,8 +85,6 @@ def mixed_prefill_attention_pallas(
     v_pool: jax.Array,
     block_tables: jax.Array,  # (B, n_t) int32 pool ids per cache slot
     desc: jax.Array,  # (R, 4) int32 (slot, q_start, q_len, kv_len)
-    *,
-    interpret: bool = True,
 ):
     """Paged flash attention for a mixed prefill+decode batch: descriptors
     plus the block table ride scalar prefetch; K/V stream from the pool
@@ -122,16 +122,21 @@ def mixed_prefill_attention_pallas(
             pltpu.VMEM((w * g, dh), jnp.float32),
         ],
     )
-    o, m, l = pl.pallas_call(
-        functools.partial(_mixed_kernel, bs=bs, scale=scale, n_t=n_t, g=g),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((r, kv, w * g, dh), jnp.float32),
-            jax.ShapeDtypeStruct((r, kv, w * g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((r, kv, w * g, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(desc.astype(jnp.int32), block_tables.astype(jnp.int32), qg, kt, vt)
+    def build(interpret):
+        return pl.pallas_call(
+            functools.partial(_mixed_kernel, bs=bs, scale=scale, n_t=n_t, g=g),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((r, kv, w * g, dh), jnp.float32),
+                jax.ShapeDtypeStruct((r, kv, w * g, 1), jnp.float32),
+                jax.ShapeDtypeStruct((r, kv, w * g, 1), jnp.float32),
+            ],
+            interpret=interpret,
+        )
+
+    o, m, l = on_backend(build)(
+        desc.astype(jnp.int32), block_tables.astype(jnp.int32), qg, kt, vt
+    )
     out = o / jnp.maximum(l, 1e-30)
     out = out.reshape(r, kv, w, g, dh).transpose(0, 2, 1, 3, 4)
     return out.reshape(r, w, h, dh).astype(q.dtype)

@@ -14,7 +14,7 @@ from repro.kernels.chunked_prefill.ref import (  # noqa: F401  (partials re-expo
 def mixed_prefill_attention(q, k_pool, v_pool, block_tables, desc, use_pallas: bool = False):
     """Ragged mixed prefill/decode attention through a block table over a
     shared KV pool.  ``use_pallas=True`` streams pool blocks via
-    scalar-prefetch index maps (TPU target; interpret elsewhere); the
+    scalar-prefetch index maps (interpreted on the CPU); the
     default gathers in XLA.
 
     ``desc`` is ``(B, 4)`` int32 rows ``(slot, q_start, q_len, kv_len)``.
@@ -33,8 +33,5 @@ def mixed_prefill_attention(q, k_pool, v_pool, block_tables, desc, use_pallas: b
         what makes greedy accept-prefix bit-identical to 1-token decode.
     """
     if use_pallas:
-        return mixed_prefill_attention_pallas(
-            q, k_pool, v_pool, block_tables, desc,
-            interpret=jax.default_backend() != "tpu",
-        )
+        return mixed_prefill_attention_pallas(q, k_pool, v_pool, block_tables, desc)
     return mixed_prefill_attention_ref(q, k_pool, v_pool, block_tables, desc)

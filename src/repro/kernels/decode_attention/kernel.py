@@ -21,6 +21,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import on_backend
+
 NEG_INF = -1e30
 
 
@@ -67,7 +69,6 @@ def decode_attention_pallas(
     lengths: jax.Array,  # (B,) valid cache length per sequence
     *,
     bs: int = 512,
-    interpret: bool = True,
     return_partials: bool = False,
 ):
     b, h, dh = q.shape
@@ -82,32 +83,35 @@ def decode_attention_pallas(
     vt = v_cache.transpose(0, 2, 1, 3)
     grid = (b, kv, s // bs)
 
-    o, m, l = pl.pallas_call(
-        functools.partial(_kernel, bs=bs, scale=scale, n_s=s // bs),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, g, dh), lambda bi, ki, sj: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, 1, bs, dh), lambda bi, ki, sj: (bi, ki, sj, 0)),
-            pl.BlockSpec((1, 1, bs, dh), lambda bi, ki, sj: (bi, ki, sj, 0)),
-            pl.BlockSpec((1,), lambda bi, ki, sj: (bi,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, g, dh), lambda bi, ki, sj: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda bi, ki, sj: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda bi, ki, sj: (bi, ki, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kv, g, dh), jnp.float32),
-            jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dh), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qg, kt.reshape(b, kv, s, dh) if kt.shape != (b, kv, s, dh) else kt, vt, lengths)
+    def build(interpret):
+        return pl.pallas_call(
+            functools.partial(_kernel, bs=bs, scale=scale, n_s=s // bs),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, g, dh), lambda bi, ki, sj: (bi, ki, 0, 0)),
+                pl.BlockSpec((1, 1, bs, dh), lambda bi, ki, sj: (bi, ki, sj, 0)),
+                pl.BlockSpec((1, 1, bs, dh), lambda bi, ki, sj: (bi, ki, sj, 0)),
+                pl.BlockSpec((1,), lambda bi, ki, sj: (bi,)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, g, dh), lambda bi, ki, sj: (bi, ki, 0, 0)),
+                pl.BlockSpec((1, 1, g, 1), lambda bi, ki, sj: (bi, ki, 0, 0)),
+                pl.BlockSpec((1, 1, g, 1), lambda bi, ki, sj: (bi, ki, 0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b, kv, g, dh), jnp.float32),
+                jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32),
+                jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((g, 1), jnp.float32),
+                pltpu.VMEM((g, 1), jnp.float32),
+                pltpu.VMEM((g, dh), jnp.float32),
+            ],
+            interpret=interpret,
+        )
+
+    o, m, l = on_backend(build)(qg, kt, vt, lengths)
     if return_partials:
         return o, m, l  # caller combines across shards then normalizes
     out = o / jnp.maximum(l, 1e-30)
@@ -163,8 +167,6 @@ def paged_decode_attention_pallas(
     v_pool: jax.Array,
     block_tables: jax.Array,  # (B, n_max_blocks) int32 pool ids per row
     lengths: jax.Array,  # (B,) valid cache length per sequence
-    *,
-    interpret: bool = True,
 ):
     """Flash-decode over a PAGED KV cache: same online-softmax stream as
     ``decode_attention_pallas``, but the sequence axis is a block table —
@@ -201,16 +203,21 @@ def paged_decode_attention_pallas(
             pltpu.VMEM((g, dh), jnp.float32),
         ],
     )
-    o, m, l = pl.pallas_call(
-        functools.partial(_paged_kernel, bs=bs, scale=scale, n_t=n_t),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kv, g, dh), jnp.float32),
-            jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), qg, kt, vt)
+    def build(interpret):
+        return pl.pallas_call(
+            functools.partial(_paged_kernel, bs=bs, scale=scale, n_t=n_t),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((b, kv, g, dh), jnp.float32),
+                jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32),
+                jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32),
+            ],
+            interpret=interpret,
+        )
+
+    o, m, l = on_backend(build)(
+        block_tables.astype(jnp.int32), lengths.astype(jnp.int32), qg, kt, vt
+    )
     out = o / jnp.maximum(l, 1e-30)
     return out.reshape(b, h, dh).astype(q.dtype)
 

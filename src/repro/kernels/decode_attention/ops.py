@@ -17,9 +17,7 @@ from repro.kernels.decode_attention.ref import (
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
 def decode_attention(q, k_cache, v_cache, lengths, use_pallas: bool = False):
     if use_pallas:
-        return decode_attention_pallas(
-            q, k_cache, v_cache, lengths, interpret=jax.default_backend() != "tpu"
-        )
+        return decode_attention_pallas(q, k_cache, v_cache, lengths)
     return decode_attention_ref(q, k_cache, v_cache, lengths)
 
 
@@ -27,10 +25,7 @@ def decode_attention(q, k_cache, v_cache, lengths, use_pallas: bool = False):
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, use_pallas: bool = False):
     """Single-token attention through a block table over a shared KV pool.
     ``use_pallas=True`` streams pool blocks via scalar-prefetch index maps
-    (TPU target; interpret elsewhere); the default gathers in XLA."""
+    (interpreted on the CPU); the default gathers in XLA."""
     if use_pallas:
-        return paged_decode_attention_pallas(
-            q, k_pool, v_pool, block_tables, lengths,
-            interpret=jax.default_backend() != "tpu",
-        )
+        return paged_decode_attention_pallas(q, k_pool, v_pool, block_tables, lengths)
     return paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths)

@@ -19,6 +19,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import on_backend
+
 NEG_INF = -1e30
 
 
@@ -73,7 +75,6 @@ def flash_attention_pallas(
     causal: bool = True,
     bq: int = 256,
     bk: int = 512,
-    interpret: bool = True,
 ):
     b, sq, h, dh = q.shape
     sk, kv = k.shape[1], k.shape[2]
@@ -92,23 +93,26 @@ def flash_attention_pallas(
     def kv_map(bh, qi, kj):
         return (bh // h) * kv + (bh % h) // group, kj, 0
 
-    out = pl.pallas_call(
-        functools.partial(
-            _kernel, causal=causal, bq=bq, bk=bk, scale=scale, n_k=sk // bk
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, dh), lambda bh, qi, kj: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, dh), kv_map),
-            pl.BlockSpec((1, bk, dh), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, bq, dh), lambda bh, qi, kj: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, dh), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, dh), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qt, kt, vt)
+    def build(interpret):
+        return pl.pallas_call(
+            functools.partial(
+                _kernel, causal=causal, bq=bq, bk=bk, scale=scale, n_k=sk // bk
+            ),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, bq, dh), lambda bh, qi, kj: (bh, qi, 0)),
+                pl.BlockSpec((1, bk, dh), kv_map),
+                pl.BlockSpec((1, bk, dh), kv_map),
+            ],
+            out_specs=pl.BlockSpec((1, bq, dh), lambda bh, qi, kj: (bh, qi, 0)),
+            out_shape=jax.ShapeDtypeStruct((b * h, sq, dh), q.dtype),
+            scratch_shapes=[
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, dh), jnp.float32),
+            ],
+            interpret=interpret,
+        )
+
+    out = on_backend(build)(qt, kt, vt)
     return out.reshape(b, h, sq, dh).transpose(0, 2, 1, 3)

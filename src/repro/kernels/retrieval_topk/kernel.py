@@ -19,14 +19,13 @@ candidate block, then keep the first K lanes — one fused pass replaces
 the former K sequential argmax-extraction sweeps, so merge cost no longer
 scales with K.  Two equivalent implementations, auto-selected:
 
-  xla      ``lax.sort_key_val`` (stable) — interpret mode / CPU, where the
-           sort primitive lowers natively
+  xla      ``lax.sort_key_val`` (stable) — interpret mode on the CPU, where
+           the sort primitive lowers natively
   bitonic  an explicit compare-exchange network of roll/where ops (padded
            to a power of two, index tie-break) — every op is VPU-native,
            for compiled TPU where Mosaic has no sort lowering
 
-`interpret` auto-selects from the backend (compiled on TPU, interpreter
-everywhere else) unless overridden explicitly.
+Compiled or interpreted follows ``repro.kernels.on_backend``.
 """
 from __future__ import annotations
 
@@ -35,6 +34,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import on_backend
 
 _I32_MAX = jnp.iinfo(jnp.int32).max
 
@@ -109,21 +110,15 @@ def retrieval_topk_pallas(
     *,
     bq: int = 128,
     bn: int = 512,
-    interpret: bool | None = None,
     merge: str | None = None,
 ):
     """queries: (Q, D); corpus: (N, D).  Returns (scores (Q,k) f32, idx (Q,k) i32).
 
     Q and N are padded up to block multiples internally; padded corpus rows
-    are masked with -inf, padded query rows are sliced off.  ``interpret``
-    defaults to compiled on TPU and interpreter mode elsewhere; ``merge``
+    are masked with -inf, padded query rows are sliced off.  ``merge``
     defaults to the XLA sort primitive under the interpreter and the
     bitonic network when compiled.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if merge is None:
-        merge = "xla" if interpret else "bitonic"
     q, d = queries.shape
     n = corpus.shape[0]
     # clamp the query block to the batch, rounded up to a sublane multiple
@@ -138,21 +133,26 @@ def retrieval_topk_pallas(
         corpus = jnp.pad(corpus, ((0, np_ - n), (0, 0)))
 
     grid = (qp // bq, np_ // bn)
-    scores, idx = pl.pallas_call(
-        functools.partial(_kernel, k=k, bn=bn, n_valid=n, merge=merge),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((qp, k), jnp.float32),
-            jax.ShapeDtypeStruct((qp, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(queries, corpus)
+
+    def build(interpret):
+        how = merge or ("xla" if interpret else "bitonic")
+        return pl.pallas_call(
+            functools.partial(_kernel, k=k, bn=bn, n_valid=n, merge=how),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
+                pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
+                pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((qp, k), jnp.float32),
+                jax.ShapeDtypeStruct((qp, k), jnp.int32),
+            ],
+            interpret=interpret,
+        )
+
+    scores, idx = on_backend(build)(queries, corpus)
     return scores[:q], idx[:q]

@@ -1,6 +1,6 @@
-"""Jitted public wrapper: picks the Pallas kernel on TPU, the jnp oracle
-elsewhere (the kernel auto-selects interpret mode from the backend, so
-CPU dry-runs / tests run the same code through the interpreter)."""
+"""Jitted public wrapper: the Pallas kernel behind ``use_pallas``, the jnp
+oracle otherwise (the kernel runs interpreted on the CPU, so CPU tests run
+the same code through the interpreter)."""
 import functools
 
 import jax

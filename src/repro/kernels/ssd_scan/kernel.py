@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import on_backend
 
 def _kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, st_ref, dec_ref, *, l):
     x = x_ref[0, :, 0].astype(jnp.float32)  # (L, hd)
@@ -46,7 +47,7 @@ def _kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, st_ref, dec_ref, *, l):
     dec_ref[0, 0] = jnp.exp(cum[-1, 0]).astype(dec_ref.dtype)
 
 
-def ssd_chunk_pallas(x, b, c, dt, a, *, interpret: bool = True):
+def ssd_chunk_pallas(x, b, c, dt, a):
     """One-chunk SSD terms per (batch, head).
 
     x: (B, L, H, hd); b/c: (B, L, H, ds) (groups pre-broadcast);
@@ -57,26 +58,29 @@ def ssd_chunk_pallas(x, b, c, dt, a, *, interpret: bool = True):
     bsz, l, h, hd = x.shape
     ds = b.shape[-1]
     grid = (bsz, h)
-    y, st, dec = pl.pallas_call(
-        functools.partial(_kernel, l=l),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, l, 1, hd), lambda bi, hi: (bi, 0, hi, 0)),
-            pl.BlockSpec((1, l, 1, ds), lambda bi, hi: (bi, 0, hi, 0)),
-            pl.BlockSpec((1, l, 1, ds), lambda bi, hi: (bi, 0, hi, 0)),
-            pl.BlockSpec((1, l, 1), lambda bi, hi: (bi, 0, hi)),
-            pl.BlockSpec((1,), lambda bi, hi: (hi,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, l, 1, hd), lambda bi, hi: (bi, 0, hi, 0)),
-            pl.BlockSpec((1, 1, hd, ds), lambda bi, hi: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1), lambda bi, hi: (bi, hi)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bsz, l, h, hd), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, h, hd, ds), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, h), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x, b, c, dt, a)
+    def build(interpret):
+        return pl.pallas_call(
+            functools.partial(_kernel, l=l),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, l, 1, hd), lambda bi, hi: (bi, 0, hi, 0)),
+                pl.BlockSpec((1, l, 1, ds), lambda bi, hi: (bi, 0, hi, 0)),
+                pl.BlockSpec((1, l, 1, ds), lambda bi, hi: (bi, 0, hi, 0)),
+                pl.BlockSpec((1, l, 1), lambda bi, hi: (bi, 0, hi)),
+                pl.BlockSpec((1,), lambda bi, hi: (hi,)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, l, 1, hd), lambda bi, hi: (bi, 0, hi, 0)),
+                pl.BlockSpec((1, 1, hd, ds), lambda bi, hi: (bi, hi, 0, 0)),
+                pl.BlockSpec((1, 1), lambda bi, hi: (bi, hi)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bsz, l, h, hd), jnp.float32),
+                jax.ShapeDtypeStruct((bsz, h, hd, ds), jnp.float32),
+                jax.ShapeDtypeStruct((bsz, h), jnp.float32),
+            ],
+            interpret=interpret,
+        )
+
+    y, st, dec = on_backend(build)(x, b, c, dt, a)
     return y, st, dec

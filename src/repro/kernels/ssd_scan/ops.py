@@ -10,5 +10,5 @@ from repro.kernels.ssd_scan.ref import ssd_chunk_ref
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
 def ssd_chunk(x, b, c, dt, a, use_pallas: bool = False):
     if use_pallas:
-        return ssd_chunk_pallas(x, b, c, dt, a, interpret=jax.default_backend() != "tpu")
+        return ssd_chunk_pallas(x, b, c, dt, a)
     return ssd_chunk_ref(x, b, c, dt, a)

@@ -8,9 +8,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-from repro.runtime.compat import make_mesh, make_topology_mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -25,12 +23,12 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             "device_count=512 before any jax import"
         )
     if len(devices) == need:
-        return make_topology_mesh(shape, axes)  # topology-aware ordering
+        # topology-aware device ordering on real TPU slices
+        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
     # device superset (e.g. single-pod mesh inside the 512-device dry-run
     # process): take the first pod's worth.
-    return make_mesh(np.array(devices[:need]).reshape(shape), axes)
+    return Mesh(np.array(devices[:need]).reshape(shape), axes)
 
 
 def make_smoke_mesh(shape=(1, 1), axes=("data", "model")) -> Mesh:
-    arr = np.array(jax.devices()[: int(np.prod(shape))]).reshape(shape)
-    return make_mesh(arr, axes)
+    return Mesh(np.array(jax.devices()[: int(np.prod(shape))]).reshape(shape), axes)

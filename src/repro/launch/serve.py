@@ -19,34 +19,14 @@ latency (see examples/federated_medqa.py for the trained-LM loop)."""
 from __future__ import annotations
 
 import argparse
-import os
-import sys
-
-# --shards N partitions the KV pool over N devices; on a CPU host that
-# means faking the device count, which only works BEFORE jax first
-# imports — peek argv here, ahead of every repro/jax import below
-if "--shards" in sys.argv or any(a.startswith("--shards=") for a in sys.argv):
-    try:
-        _i = sys.argv.index("--shards")
-        _n = int(sys.argv[_i + 1])
-    except (ValueError, IndexError):
-        _n = next(
-            (int(a.split("=", 1)[1]) for a in sys.argv if a.startswith("--shards=")),
-            1,
-        )
-    if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={max(_n, 1)} "
-            + os.environ.get("XLA_FLAGS", "")
-        )
 
 import numpy as np
 
 from repro.core.pipeline import CFedRAGConfig, CFedRAGSystem
 from repro.core.resilience import FaultSpec
 from repro.data.corpus import make_federated_corpus
-from repro.data.embeddings import bag_embed
 from repro.data.tokenizer import HashTokenizer
+from repro.runtime.compile_cache import use_compile_cache
 
 
 def overlap_reranker(tok: HashTokenizer):
@@ -209,8 +189,8 @@ def main(argv=None):
         "--shards", type=int, default=None, metavar="N",
         help="partition the paged KV pool over N mesh devices (row-affine "
         "blocks, one distributed mixed dispatch per step, bit-identical "
-        "to --shards 1); on a CPU host the launcher fakes N host devices "
-        "via XLA_FLAGS before jax loads (implies --paged --generate)",
+        "to --shards 1; implies --paged --generate).  Needs N devices; "
+        "the README shows how a CPU host fakes them",
     )
     ap.add_argument(
         "--repeat", type=int, default=1,
@@ -259,6 +239,7 @@ def main(argv=None):
         "calibration + outlier-round quarantine",
     )
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.spill_mb is not None:
         args.prefix_cache = True
     if (args.prefix_cache or args.token_budget is not None or args.draft_k > 0

@@ -8,7 +8,7 @@ Attention impls:
   naive      materialized S_q x S_k logits (small seq, oracle)
   flash_jnp  lax.scan over KV chunks with online softmax — the dry-run /
              XLA production path (O(S·chunk) memory, exact)
-  pallas     kernels/flash_attention (TPU target; validated in interpret mode)
+  pallas     kernels/flash_attention (compiled on TPU, interpreted on the CPU)
 """
 from __future__ import annotations
 
@@ -239,7 +239,6 @@ def _paged_attn_sharded(cfg: ModelConfig, q, k_new, v_new, k_pool, v_pool,
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.chunked_prefill.ref import mixed_prefill_partials
-    from repro.runtime.compat import shard_map
     from repro.serving.dist_decode import combine_partials
 
     b, w, h, dh = q.shape
@@ -270,7 +269,7 @@ def _paged_attn_sharded(cfg: ModelConfig, q, k_new, v_new, k_pool, v_pool,
         out = out.transpose(0, 3, 1, 2, 4).reshape(b, w, kv * (h // kv), dh)
         return out.astype(q.dtype), k_sh[None], v_sh[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P("data"), P("data"), P(), P(), P(), P(), P()),
@@ -302,7 +301,7 @@ def attn_decode_paged(cfg: ModelConfig, pol: ShardingPolicy, p, x, k_pool, v_poo
       * ``attn_impl="pallas"``: ``kernels/decode_attention``'s paged
         flash-decode kernel — the scalar-prefetched block table drives
         the K/V BlockSpec index maps, so the gather never materializes in
-        HBM (interpret mode off-TPU; numerically equal to the XLA path
+        HBM (interpreted on the CPU; numerically equal to the XLA path
         within flash-softmax reassociation tolerance, parity-tested in
         tests/test_models.py).
     """
@@ -375,7 +374,7 @@ def attn_mixed_paged(cfg: ModelConfig, pol: ShardingPolicy, p, x, k_pool, v_pool
         lanes output exactly 0).
       * ``attn_impl="pallas"``: ``kernels/chunked_prefill``'s unified
         kernel — descriptors + block table ride scalar prefetch, pool
-        blocks stream straight into VMEM (interpret mode off-TPU).
+        blocks stream straight into VMEM (interpreted on the CPU).
 
     Returns ``(o, k_pool, v_pool)`` with the fresh K/V already resident.
     """

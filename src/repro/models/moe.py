@@ -25,7 +25,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.models.params import ParamSpec
 from repro.models.layers import mlp_specs, mlp_apply
-from repro.runtime.compat import axis_size, shard_map
 from repro.runtime.sharding import ShardingPolicy
 
 
@@ -83,7 +82,7 @@ def _expert_compute(wg, wu, wd, xbuf, group_sizes):
 def _local_moe(cfg: ModelConfig, cap: int, axis_names: tuple, p, x_loc):
     """Per-device body under shard_map.  x_loc: (T_loc, d) replicated over
     `model`; p["wg"/"wu"/"wd"] are the local expert shards (E_loc, ...)."""
-    tp = axis_size("model")
+    tp = jax.lax.axis_size("model")
     my = jax.lax.axis_index("model")
     e_loc = p["wg"].shape[0]
     t_loc = x_loc.shape[0]
@@ -133,7 +132,7 @@ def moe_apply(cfg: ModelConfig, pol: ShardingPolicy, p, x):
         tok_axes = batch_rule if batch_rule else None
         tok_spec = P(tok_axes, None)
         axis_names = tuple(mesh.axis_names)
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             partial(_local_moe, cfg, cap, axis_names),
             mesh=mesh,
             in_specs=(_moe_param_specs(p), tok_spec),
@@ -201,7 +200,7 @@ def _local_moe_a2a(cfg: ModelConfig, cap: int, axis_names: tuple, p, x_loc):
     2 x cap x tp x d x 2B (there + back, bf16) vs the psum variant's
     2 x T_loc x d per direction — a ~tp/(2k·slack) reduction
     (EXPERIMENTS.md §Perf cell B)."""
-    tp = axis_size("model")
+    tp = jax.lax.axis_size("model")
     my = jax.lax.axis_index("model")
     e_loc = p["wg"].shape[0]
     t_loc = x_loc.shape[0]
@@ -266,7 +265,7 @@ def moe_apply_a2a(cfg: ModelConfig, pol: ShardingPolicy, p, x):
     tok_axes = tuple(a for a in (batch_rule if isinstance(batch_rule, tuple) else (batch_rule,)) if a)
     tok_spec = P(tuple(tok_axes) + ("model",) if "model" not in tok_axes else tok_axes, None)
     x2d = x.reshape(b * s, d)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         partial(_local_moe_a2a, cfg, cap, tuple(mesh.axis_names)),
         mesh=mesh,
         in_specs=(_moe_param_specs(p), tok_spec),

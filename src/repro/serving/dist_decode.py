@@ -29,8 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.runtime.compat import shard_map
-
 
 def combine_partials(o, m, l, *, axis_name: str):
     """Merge per-shard flash-softmax partials across ``axis_name``.
@@ -75,7 +73,7 @@ def dist_decode_attention(
     mesh,
     axis_name: str = "data",
 ):
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_local_partials, axis_name=axis_name),
         mesh=mesh,
         in_specs=(P(), P(None, axis_name, None, None), P(None, axis_name, None, None), P()),

@@ -102,6 +102,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.configs.base import ModelConfig
 from repro.data.tokenizer import EOS, PAD
@@ -310,10 +311,11 @@ class ServeEngine:
                     f"{len(devs)} (CPU: set XLA_FLAGS="
                     "--xla_force_host_platform_device_count before importing jax)"
                 )
-            from repro.runtime import compat
-
-            self._mesh = compat.make_mesh(
-                np.array(devs[: scfg.shards]), ("data",)
+            self._mesh = Mesh(np.array(devs[: scfg.shards]), ("data",))
+            # weights are replicated over the mesh ONCE here; left on the
+            # default device they would be copied to every shard per step
+            self.params = params = jax.device_put(
+                params, NamedSharding(self._mesh, PartitionSpec())
             )
         if scfg.prefix_cache and not scfg.paged:
             raise ValueError(
@@ -380,9 +382,12 @@ class ServeEngine:
                     "through its own paged pool"
                 )
             self._draft_cfg = dcfg
-            self._draft_params = (
-                scfg.draft_params if scfg.draft_params is not None else params
-            )
+            dparams = scfg.draft_params if scfg.draft_params is not None else params
+            if self._mesh is not None:
+                dparams = jax.device_put(
+                    dparams, NamedSharding(self._mesh, PartitionSpec())
+                )
+            self._draft_params = dparams
         t_cap = scfg.max_new_tokens
         # dispatch observability: fused admit prefills (bucketed admission
         # benchmark), fused decode chunks, and unified mixed steps — the
@@ -805,10 +810,8 @@ class ServeEngine:
         """Lay a sharded paged cache out over the mesh: pool leaves split
         on the shard axis ``P(None, "data", ...)``, per-slot leaves
         replicated — each device then holds exactly its shard's blocks."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        pool_s = NamedSharding(self._mesh, P(None, "data"))
-        repl_s = NamedSharding(self._mesh, P())
+        pool_s = NamedSharding(self._mesh, PartitionSpec(None, "data"))
+        repl_s = NamedSharding(self._mesh, PartitionSpec())
         return jax.tree.map(
             lambda leaf: jax.device_put(
                 leaf, pool_s if self._is_pool_leaf(leaf) else repl_s

@@ -3,18 +3,6 @@ import os
 # smoke tests and benches must see 1 device (the dry-run sets 512 itself)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-try:  # property tests prefer the real library when available
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    import importlib.util as _ilu
-
-    _spec = _ilu.spec_from_file_location(
-        "_hypothesis_stub", os.path.join(os.path.dirname(__file__), "_hypothesis_stub.py")
-    )
-    _stub = _ilu.module_from_spec(_spec)
-    _spec.loader.exec_module(_stub)
-    _stub.install()
-
 import jax
 import numpy as np
 import pytest

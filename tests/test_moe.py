@@ -84,8 +84,7 @@ def test_moe_sharded_equals_single_device(impl):
         key = jax.random.PRNGKey(0)
         p = init_params(moe_specs(cfg, tp_hint=4), key)
         x = jax.random.normal(key, (4, 16, cfg.d_model))
-        from repro.runtime.compat import make_mesh
-        mesh = make_mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
+        mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
         pol = ShardingPolicy(rules=base_rules(False), mesh=mesh)
         out_sharded, aux_s = jax.jit(lambda p, x: moe_apply(cfg, pol, p, x))(p, x)
         ref, aux_r = moe_reference(cfg, p, x)

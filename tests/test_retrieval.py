@@ -57,8 +57,8 @@ def _spawn_multidevice_check():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.retrieval import federated_topk
         from repro.kernels.retrieval_topk.ref import retrieval_topk_ref
-        from repro.runtime.compat import make_mesh
-        mesh = make_mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
+        from jax.sharding import Mesh
+        mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
         k = jax.random.PRNGKey(0)
         q = jax.random.normal(k, (4, 32))
         c = jax.random.normal(jax.random.fold_in(k, 1), (128, 32))

@@ -77,8 +77,7 @@ def test_dist_decode_matches_oracle_8dev():
         from repro.serving.dist_decode import dist_decode_attention
         from repro.kernels.decode_attention.ref import decode_attention_ref
 
-        from repro.runtime.compat import make_mesh
-        mesh = make_mesh(np.array(jax.devices()).reshape(8,), ("data",))
+        mesh = Mesh(np.array(jax.devices()).reshape(8,), ("data",))
         k = jax.random.PRNGKey(0)
         b, s, h, kv, dh = 2, 128, 8, 4, 32
         q = jax.random.normal(k, (b, h, dh))

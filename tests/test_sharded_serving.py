@@ -19,7 +19,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs import get_config, smoke_config
 from repro.kernels.chunked_prefill.ref import (
@@ -29,7 +29,6 @@ from repro.kernels.chunked_prefill.ref import (
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.models import lm as LM
 from repro.models.params import init_params
-from repro.runtime import compat
 from repro.runtime.sharding import ShardingPolicy, base_rules
 from repro.serving.dist_decode import combine_partials, dist_decode_attention
 from repro.serving.engine import ServeConfig, ServeEngine
@@ -51,7 +50,7 @@ def small_lm():
 
 
 def _mesh(n):
-    return compat.make_mesh(np.array(jax.devices()[:n]), ("data",))
+    return Mesh(np.array(jax.devices()[:n]), ("data",))
 
 
 # ------------------------------------------------------------------ #
@@ -102,7 +101,7 @@ def test_combine_passes_owner_through_bitwise():
         l_s = jnp.where(mine, l, 0.0)
         return combine_partials(o_s, m_s, l_s, axis_name="data")
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(P(), P(), P(), P()), out_specs=P(),
         check_vma=False,
     )
