@@ -65,9 +65,9 @@ class ModelConfig:
     param_dtype: str = "float32"
     logit_dtype: str = "float32"
     bf16_grads: bool = False  # bf16 gradient sync (f32 master update)
-    scan_unroll: bool = False  # unroll all scans (dry-run cost measurement:
-    # XLA cost_analysis counts while-loop bodies ONCE, so roofline
-    # measurement compiles must be loop-free; see launch/dryrun.py)
+    scan_unroll: bool = False  # unroll all scans (static cost analysis:
+    # XLA cost_analysis counts while-loop bodies ONCE, so a compile whose
+    # cost is read must be loop-free)
 
     # ------------------------------------------------------------------ #
     def with_overrides(self, **kw) -> "ModelConfig":

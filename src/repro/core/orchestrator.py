@@ -60,6 +60,7 @@ from repro.core.resilience import (
     ScoreGate,
 )
 from repro.data.tokenizer import ANS, BOS, CTX, EOS, PAD, QRY, SEP, HashTokenizer
+from repro.runtime import tracing
 
 # the faults one provider may raise without failing the round: absorbed
 # by quorum (Algorithm 1's k_n <= k), counted in the health ledger.  An
@@ -116,6 +117,7 @@ class Orchestrator:
         }
         self.enclave = Enclave("cfedrag-orchestrator-v1")
         self._establish_channels()
+        self.rounds = 0  # batched collects so far; the id of the latest
 
     def _establish_channels(self):
         """Mutual attestation with every provider (paper §2.3.1 mTLS): each
@@ -334,13 +336,15 @@ class Orchestrator:
         unexpected: list[BaseException] = []
         n_finished = [0]
         cond = threading.Condition()
+        parent = tracing.current()  # the round's span, for the workers' spans
 
         def worker(i, p):
             resp = None
             try:
                 # expected faults (dead link, timeout, tampered payload)
                 # are absorbed inside _exchange -> None; quorum decides
-                resp = self._exchange(p, tokens_for, t0)
+                with tracing.under(parent):
+                    resp = self._exchange(p, tokens_for, t0)
             except BaseException as e:  # real bugs must surface, not vanish
                 with cond:
                     unexpected.append(e)
@@ -429,7 +433,10 @@ class Orchestrator:
                 [np.pad(r, (0, width - len(r))) for r in rows]
             ).astype(np.int32)  # PAD tail; the embedder masks PAD
 
-        return self._collect(fan, tokens_for)
+        self.rounds += 1
+        with tracing.span("fed.collect", round=self.rounds, batch=len(queries),
+                          providers=len(fan)):
+            return self._collect(fan, tokens_for)
 
     def _gate_responses(self, responses: list[dict]) -> tuple[list[dict], dict | None]:
         """Aggregator-side poisoning gate (opt-in, ``score_gate``): each
@@ -500,42 +507,44 @@ class Orchestrator:
         """Step 4 over a batch: one re-rank pass over the (B, C, S)
         candidate block when the reranker supports batching, else per-row.
         Produces per-query context dicts identical to ``aggregate``."""
-        responses, gated = self._gate_responses(responses)
-        all_tokens = np.concatenate([r["chunk_tokens"] for r in responses], 1)  # (B, C, S)
-        all_ids = np.concatenate([r["chunk_ids"] for r in responses], 1)  # (B, C)
-        all_scores = np.concatenate([r["scores"] for r in responses], 1)
-        providers = np.concatenate(
-            [
-                np.full(r["chunk_ids"].shape, int(r["provider"]))
-                for r in responses
-            ],
-            1,
-        )
-        if self.aggregation == "rerank" and self.reranker is not None:
-            q_tok = np.stack([self.tok.encode(q, max_len=24) for q in queries])
-            if getattr(self.reranker, "supports_batch", False):
-                rank_scores = np.asarray(self.reranker(q_tok, all_tokens))
+        with tracing.span("fed.aggregate", round=self.rounds):
+            responses, gated = self._gate_responses(responses)
+            all_tokens = np.concatenate([r["chunk_tokens"] for r in responses], 1)  # (B, C, S)
+            all_ids = np.concatenate([r["chunk_ids"] for r in responses], 1)  # (B, C)
+            all_scores = np.concatenate([r["scores"] for r in responses], 1)
+            providers = np.concatenate(
+                [
+                    np.full(r["chunk_ids"].shape, int(r["provider"]))
+                    for r in responses
+                ],
+                1,
+            )
+            if self.aggregation == "rerank" and self.reranker is not None:
+                q_tok = np.stack([self.tok.encode(q, max_len=24) for q in queries])
+                if getattr(self.reranker, "supports_batch", False):
+                    rank_scores = np.asarray(self.reranker(q_tok, all_tokens))
+                else:
+                    rank_scores = np.stack([
+                        np.asarray(self.reranker(q_tok[b], all_tokens[b]))
+                        for b in range(len(queries))
+                    ])
             else:
-                rank_scores = np.stack(
-                    [np.asarray(self.reranker(q_tok[b], all_tokens[b])) for b in range(len(queries))]
-                )
-        else:
-            rank_scores = all_scores
-        n = min(self.n_global, all_ids.shape[1])
-        outs = []
-        for b in range(len(queries)):
-            order = np.argsort(-rank_scores[b])[:n]
-            ctx = {
-                "chunk_tokens": all_tokens[b][order],
-                "chunk_ids": all_ids[b][order],
-                "scores": rank_scores[b][order],
-                "providers": providers[b][order],
-                "n_candidates": all_ids.shape[1],
-            }
-            if gated is not None:
-                ctx["gated"] = gated
-            outs.append(ctx)
-        return outs
+                rank_scores = all_scores
+            n = min(self.n_global, all_ids.shape[1])
+            outs = []
+            for b in range(len(queries)):
+                order = np.argsort(-rank_scores[b])[:n]
+                ctx = {
+                    "chunk_tokens": all_tokens[b][order],
+                    "chunk_ids": all_ids[b][order],
+                    "scores": rank_scores[b][order],
+                    "providers": providers[b][order],
+                    "n_candidates": all_ids.shape[1],
+                }
+                if gated is not None:
+                    ctx["gated"] = gated
+                outs.append(ctx)
+            return outs
 
     def build_prompt(self, query_text: str, context: dict, max_len: int = 512) -> np.ndarray:
         """[BOS] CTX chunk1 SEP chunk2 ... QRY query ANS — a STABLE
@@ -557,24 +566,25 @@ class Orchestrator:
         the ``BOS/CTX/QRY/query/ANS`` skeleton intact, where a blind
         ``ids[-max_len:]`` would slice off ``BOS``/``CTX`` and could
         bisect a chunk."""
-        query = [int(t) for t in self.tok.encode(query_text, bos=False) if t not in (PAD, EOS)]
-        n_markers = 4  # BOS, CTX, QRY, ANS
-        # fixed reserve: chunk inclusion must not depend on the query, or
-        # same-context siblings diverge before QRY and never share blocks
-        reserve = min(self.query_reserve, max(0, (max_len - n_markers) // 2))
-        chunk_budget = max_len - n_markers - reserve
-        ids = [BOS, CTX]
-        for row in context["chunk_tokens"]:
-            chunk = [int(t) for t in row if t not in (PAD, BOS, EOS)]
-            if len(chunk) + 1 > chunk_budget:  # +1: trailing SEP
-                break  # ranked order: everything after is lower-scored
-            ids += chunk
-            ids.append(SEP)
-            chunk_budget -= len(chunk) + 1
-        ids.append(QRY)
-        ids += query[: max(0, max_len - len(ids) - 1)]  # tail cut, ANS always fits
-        ids.append(ANS)
-        return np.asarray(ids, np.int32)[None, :]
+        with tracing.span("fed.prompt", round=self.rounds):
+            query = [int(t) for t in self.tok.encode(query_text, bos=False) if t not in (PAD, EOS)]
+            n_markers = 4  # BOS, CTX, QRY, ANS
+            # fixed reserve: chunk inclusion must not depend on the query, or
+            # same-context siblings diverge before QRY and never share blocks
+            reserve = min(self.query_reserve, max(0, (max_len - n_markers) // 2))
+            chunk_budget = max_len - n_markers - reserve
+            ids = [BOS, CTX]
+            for row in context["chunk_tokens"]:
+                chunk = [int(t) for t in row if t not in (PAD, BOS, EOS)]
+                if len(chunk) + 1 > chunk_budget:  # +1: trailing SEP
+                    break  # ranked order: everything after is lower-scored
+                ids += chunk
+                ids.append(SEP)
+                chunk_budget -= len(chunk) + 1
+            ids.append(QRY)
+            ids += query[: max(0, max_len - len(ids) - 1)]  # tail cut, ANS always fits
+            ids.append(ANS)
+            return np.asarray(ids, np.int32)[None, :]
 
     def _prompt_max_len(self) -> int:
         """Generator-advertised prompt window (``max_prompt_len`` on an

@@ -30,6 +30,7 @@ from repro.core.filters import Filter, apply_filters
 from repro.data.corpus import Chunk
 from repro.data.tokenizer import HashTokenizer
 from repro.kernels.retrieval_topk.ops import retrieval_topk
+from repro.runtime import tracing
 
 
 def pack(payload: dict) -> bytes:
@@ -105,9 +106,12 @@ class DataProvider:
         if self.delay_s:
             time.sleep(self.delay_s)
         assert self.channel is not None, "no established channel"
-        req = unpack(self.channel.open(nonce, sealed))
-        out = self.retrieve(req["query_tokens"], int(req["m"]))
-        return self.channel.seal(pack(out))
+        parent = tracing.current()
+        with tracing.span("provider.request", provider=self.provider_id,
+                          round=parent.attrs.get("round") if parent else None):
+            req = unpack(self.channel.open(nonce, sealed))
+            out = self.retrieve(req["query_tokens"], int(req["m"]))
+            return self.channel.seal(pack(out))
 
     def retrieve(self, query_tokens: np.ndarray, m: int) -> dict:
         """Local top-m.  query_tokens: (S,) -> {scores (m,), chunk_ids (m,),
@@ -118,12 +122,15 @@ class DataProvider:
         single = q.ndim == 1
         if single:
             q = q[None, :]
-        q_emb = np.asarray(self.embed_fn(q))  # (B, D)
+        q_emb = self.embed_fn(q)
+        with tracing.span("provider.embed.wait"):  # blocks on the device
+            q_emb = np.asarray(q_emb)  # (B, D)
         m_eff = min(m, len(self.chunks))
         scores, idx = retrieval_topk(
             q_emb, self.embeddings, m_eff, use_pallas=self.use_pallas
         )
-        scores, idx = np.asarray(scores), np.asarray(idx)  # (B, m)
+        with tracing.span("provider.topk.wait"):
+            scores, idx = np.asarray(scores), np.asarray(idx)  # (B, m)
         if single:
             scores, idx = scores[0], idx[0]
         payload = {

@@ -366,11 +366,17 @@ def main(argv=None):
         )
     if args.generate:
         lats = sorted(r["latency_s"] for r in results if r.get("latency_s") is not None)
+        st = getattr(sys_, "last_serve_stats", {})
         if lats:
             p50 = lats[len(lats) // 2]
             p95 = lats[min(len(lats) - 1, int(len(lats) * 0.95))]
-            print(f"\ngeneration latency: p50={p50 * 1e3:.1f}ms p95={p95 * 1e3:.1f}ms")
-        st = getattr(sys_, "last_serve_stats", {})
+            line = f"\ngeneration latency: p50={p50 * 1e3:.1f}ms p95={p95 * 1e3:.1f}ms"
+            if "ttft_p95_s" in st:
+                line += (
+                    f"; first token p50={st['ttft_p50_s'] * 1e3:.1f}ms "
+                    f"p95={st['ttft_p95_s'] * 1e3:.1f}ms"
+                )
+            print(line)
         if "min_free_slots" in st:
             slots = sys_.orchestrator.generator.engine.scfg.max_batch
             line = (

@@ -97,6 +97,7 @@ rest of their stay in the batch (post-EOS logits are never emitted).
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Callable, Sequence
 
 import jax
@@ -107,6 +108,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from repro.configs.base import ModelConfig
 from repro.data.tokenizer import EOS, PAD
 from repro.models import lm as LM
+from repro.runtime import tracing
 from repro.runtime.sharding import ShardingPolicy
 from repro.serving.kv_cache import (
     BlockPool,
@@ -153,6 +155,18 @@ def resolve_fill_deps(fill_deps: dict[int, frozenset], pending) -> list[int]:
     if fill_deps and not runnable:
         raise AdmissionDeadlock([], sorted(fill_deps))
     return runnable
+
+
+def _stamp_first_tokens(slots, emitted, fills=None) -> None:
+    """Set ``first_token_at`` on each request whose first answer token the
+    read-back just brought (``emitted`` reached 1).  A row whose prompt is
+    still streaming (``fills``) holds a stale count and is skipped."""
+    now = None
+    for i, req in enumerate(slots):
+        if (req is not None and req.first_token_at is None and emitted[i] >= 1
+                and (fills is None or fills[i] is None)):
+            now = now or time.monotonic()
+            req.first_token_at = now
 
 
 def accept_prefix(draft, target, *, q_len=None, rem=None, done=None, eos=EOS):
@@ -1063,6 +1077,7 @@ class ServeEngine:
             # np.array (not asarray): device views are read-only and the
             # mirrors are written at the next admit
             em_h, dn_h = np.array(emitted), np.array(done)
+            _stamp_first_tokens(slots, em_h)
 
             retired = [i for i in active if dn_h[i]]
             if retired:
@@ -1073,6 +1088,15 @@ class ServeEngine:
                     scheduler.finish(req, ans)
                     slots[i] = None  # retire: slot free for the next admit
                     yield req.rid, ans
+
+    @staticmethod
+    def _geometry(slots, lengths, emitted, done, **descriptors) -> dict:
+        """A dispatch's live geometry from the host mirrors, as it is
+        launched: per row the prompt length, the tokens emitted, the done
+        flag and the request id (-1 for a free slot), and the dispatch's
+        own descriptors (fresh arrays each step, so not copied)."""
+        return dict(lengths=lengths.copy(), emitted=emitted.copy(), done=done.copy(),
+                    rids=[-1 if r is None else r.rid for r in slots], **descriptors)
 
     def _dispatch_lifetime(self) -> dict:
         return {
@@ -1233,450 +1257,501 @@ class ServeEngine:
 
         try:
             while True:
-                # ---- admit queued requests into free slots ----
-                # each admit is pure host bookkeeping (pool commit + fill
-                # record); NO device dispatch happens here — prompt tokens
-                # enter the device through the shared mixed step below
-                # (re-admitted spilled chunks are the one exception: their
-                # host payload uploads synchronously right here)
-                for slot in range(B):
-                    if slots[slot] is not None:
+                with tracing.span("engine.step", step=steps) as step:
+                    # ---- admit queued requests into free slots ----
+                    # each admit is pure host bookkeeping (pool commit + fill
+                    # record); NO device dispatch happens here — prompt tokens
+                    # enter the device through the shared mixed step below
+                    # (re-admitted spilled chunks are the one exception: their
+                    # host payload uploads synchronously right here)
+                    t_admit, n_admit = time.monotonic_ns(), 0
+                    for slot in range(B):
+                        if slots[slot] is not None:
+                            continue
+                        req = scheduler.pop_ready(admit_if=admit_gate)
+                        if req is None:
+                            break
+                        p = req.tokens[-width:]
+                        length = len(p)
+                        b_new = t_cap if req.max_new_tokens is None else req.max_new_tokens
+                        b_new = max(1, min(int(b_new), t_cap))
+                        start, cow, deps = 0, None, set()
+                        if index is not None:
+                            plan = planned.pop(req.rid, None) or index.plan(p)
+                            if plan is None:
+                                raise RuntimeError("prefix admit raced the block pool")
+                            table_ids, cow_dst = index.commit(plan)
+                            for payload, b in plan.uploads:
+                                # host-tier re-admission: K/V comes back by
+                                # upload, not re-prefill; materialized before
+                                # any dispatch reads it, so never "pending"
+                                if payload:
+                                    self._cache = self._upload_block(
+                                        self._cache, payload, jnp.int32(b)
+                                    )
+                            row_tables[slot].adopt(table_ids)
+                            tables_h[slot, :] = self._trash_block
+                            tables_h[slot, : len(table_ids)] = table_ids
+                            self.prefix_lookups += 1
+                            self.prefill_tokens_total += length
+                            start = plan.start
+                            if start:
+                                self.prefix_hits += 1
+                                self.prefill_tokens_saved += start
+                                self.prefix_shared_total += len(plan.shared) + (cow_dst is not None)
+                            if cow_dst is not None and plan.cow_src is not None:
+                                # device boundary copy still pending; a host
+                                # (spilled) boundary already uploaded above
+                                cow = (plan.cow_src, cow_dst)
+                            # wait on shared/COW-source chunks another in-flight
+                            # fill has registered but not yet computed
+                            deps = {
+                                b for b in (set(plan.shared) | ({plan.cow_src} if cow else set()))
+                                if b in pending_blocks
+                            }
+                            for c in range(len(plan.nodes), length // bs):
+                                pending_blocks[table_ids[c]] = (slot, (c + 1) * bs)
+                        else:
+                            tb = row_tables[slot]
+                            if not tb.extend_to(length + 1):
+                                raise RuntimeError("paged admit raced the block pool")
+                            tables_h[slot, :] = self._trash_block
+                            tables_h[slot, : tb.n_blocks] = tb.ids
+                        scheduler.record_tenant_admit(
+                            req.tenant, prefill_tokens=length,
+                            prefill_tokens_saved=start, hit=start > 0,
+                        )
+                        slots[slot] = req
+                        fills[slot] = dict(
+                            p=p, length=length, b_new=b_new, pos=start, cow=cow, deps=deps
+                        )
+                        if spec:
+                            d_tb = d_row_tables[slot]
+                            if not d_tb.extend_to(length + 1):
+                                raise RuntimeError("draft admit raced the draft pool")
+                            d_tables_h[slot, :] = self._trash_block
+                            d_tables_h[slot, : d_tb.n_blocks] = d_tb.ids
+                            d_fills[slot] = dict(p=p, length=length, pos=0)
+                            d_broken[slot] = False
+                        # inert on device until the fill's last chunk seeds the
+                        # slot (mixed_rows `completes`); done=True keeps any
+                        # decode lane from touching it meanwhile
+                        em_h[slot], dn_h[slot] = 0, True
+                        bu_h[slot], ln_h[slot] = b_new, length
+                        n_admit += 1
+                    if n_admit:
+                        tracing.record(
+                            "engine.admit", t_admit, time.monotonic_ns(), admitted=n_admit
+                        )
+
+                    active = [i for i in range(B) if slots[i] is not None]
+                    scheduler.record_occupancy(
+                        free_slots=B - len(active),
+                        free_blocks=pool.free_blocks,
+                        reclaimable_blocks=pool.reclaimable_blocks if index is not None else None,
+                        # drafter-pool headroom: without it a d_broken (drafter
+                        # OOM) degradation is invisible in the memory gauges
+                        draft_free_blocks=d_pool.free_blocks if spec else None,
+                    )
+                    report_prefix()
+                    scheduler.record_dispatch_stats(
+                        admit_dispatches=self.admit_dispatches - a0,
+                        decode_dispatches=self.decode_dispatches - d0,
+                        mixed_dispatches=self.mixed_dispatches - m0,
+                        steps=steps,
+                        lifetime=self._dispatch_lifetime(),
+                        draft_dispatches=self.draft_dispatches - dr0,
+                        draft_fill_dispatches=self.draft_fill_dispatches - df0,
+                        spec_rounds=self.spec_rounds - sr0,
+                        spec_tokens_proposed=self.spec_tokens_proposed - sp0,
+                        spec_tokens_accepted=self.spec_tokens_accepted - sa0,
+                        spec_tokens_emitted=self.spec_tokens_emitted - se0,
+                    )
+                    if not active:
+                        step.drop()  # nothing to dispatch: not an engine step
+                        if drain or scheduler.closed:
+                            if scheduler.has_pending:
+                                continue
+                            return
+                        with tracing.span("engine.wait"):
+                            scheduler.wait_for_work()
                         continue
-                    req = scheduler.pop_ready(admit_if=admit_gate)
-                    if req is None:
-                        break
-                    p = req.tokens[-width:]
-                    length = len(p)
-                    b_new = t_cap if req.max_new_tokens is None else req.max_new_tokens
-                    b_new = max(1, min(int(b_new), t_cap))
-                    start, cow, deps = 0, None, set()
-                    if index is not None:
-                        plan = planned.pop(req.rid, None) or index.plan(p)
-                        if plan is None:
-                            raise RuntimeError("prefix admit raced the block pool")
-                        table_ids, cow_dst = index.commit(plan)
-                        for payload, b in plan.uploads:
-                            # host-tier re-admission: K/V comes back by
-                            # upload, not re-prefill; materialized before
-                            # any dispatch reads it, so never "pending"
-                            if payload:
-                                self._cache = self._upload_block(
-                                    self._cache, payload, jnp.int32(b)
-                                )
-                        row_tables[slot].adopt(table_ids)
-                        tables_h[slot, :] = self._trash_block
-                        tables_h[slot, : len(table_ids)] = table_ids
-                        self.prefix_lookups += 1
-                        self.prefill_tokens_total += length
-                        start = plan.start
-                        if start:
-                            self.prefix_hits += 1
-                            self.prefill_tokens_saved += start
-                            self.prefix_shared_total += len(plan.shared) + (cow_dst is not None)
-                        if cow_dst is not None and plan.cow_src is not None:
-                            # device boundary copy still pending; a host
-                            # (spilled) boundary already uploaded above
-                            cow = (plan.cow_src, cow_dst)
-                        # wait on shared/COW-source chunks another in-flight
-                        # fill has registered but not yet computed
-                        deps = {
-                            b for b in (set(plan.shared) | ({plan.cow_src} if cow else set()))
-                            if b in pending_blocks
-                        }
-                        for c in range(len(plan.nodes), length // bs):
-                            pending_blocks[table_ids[c]] = (slot, (c + 1) * bs)
-                    else:
-                        tb = row_tables[slot]
-                        if not tb.extend_to(length + 1):
-                            raise RuntimeError("paged admit raced the block pool")
-                        tables_h[slot, :] = self._trash_block
-                        tables_h[slot, : tb.n_blocks] = tb.ids
-                    scheduler.record_tenant_admit(
-                        req.tenant, prefill_tokens=length,
-                        prefill_tokens_saved=start, hit=start > 0,
-                    )
-                    slots[slot] = req
-                    fills[slot] = dict(
-                        p=p, length=length, b_new=b_new, pos=start, cow=cow, deps=deps
-                    )
-                    if spec:
-                        d_tb = d_row_tables[slot]
-                        if not d_tb.extend_to(length + 1):
-                            raise RuntimeError("draft admit raced the draft pool")
-                        d_tables_h[slot, :] = self._trash_block
-                        d_tables_h[slot, : d_tb.n_blocks] = d_tb.ids
-                        d_fills[slot] = dict(p=p, length=length, pos=0)
-                        d_broken[slot] = False
-                    # inert on device until the fill's last chunk seeds the
-                    # slot (mixed_rows `completes`); done=True keeps any
-                    # decode lane from touching it meanwhile
-                    em_h[slot], dn_h[slot] = 0, True
-                    bu_h[slot], ln_h[slot] = b_new, length
 
-                active = [i for i in range(B) if slots[i] is not None]
-                scheduler.record_occupancy(
-                    free_slots=B - len(active),
-                    free_blocks=pool.free_blocks,
-                    reclaimable_blocks=pool.reclaimable_blocks if index is not None else None,
-                    # drafter-pool headroom: without it a d_broken (drafter
-                    # OOM) degradation is invisible in the memory gauges
-                    draft_free_blocks=d_pool.free_blocks if spec else None,
-                )
-                report_prefix()
-                scheduler.record_dispatch_stats(
-                    admit_dispatches=self.admit_dispatches - a0,
-                    decode_dispatches=self.decode_dispatches - d0,
-                    mixed_dispatches=self.mixed_dispatches - m0,
-                    steps=steps,
-                    lifetime=self._dispatch_lifetime(),
-                    draft_dispatches=self.draft_dispatches - dr0,
-                    draft_fill_dispatches=self.draft_fill_dispatches - df0,
-                    spec_rounds=self.spec_rounds - sr0,
-                    spec_tokens_proposed=self.spec_tokens_proposed - sp0,
-                    spec_tokens_accepted=self.spec_tokens_accepted - sa0,
-                    spec_tokens_emitted=self.spec_tokens_emitted - se0,
-                )
-                if not active:
-                    if drain or scheduler.closed:
-                        if scheduler.has_pending:
-                            continue
-                        return
-                    scheduler.wait_for_work()
-                    continue
-
-                fill_rows = [i for i in range(B) if fills[i] is not None]
-                dec_rows = [i for i in active if fills[i] is None and not dn_h[i]]
-                try:
-                    runnable = resolve_fill_deps(
-                        {i: frozenset(fills[i]["deps"]) for i in fill_rows},
-                        pending_blocks.keys(),
-                    )
-                except AdmissionDeadlock as exc:
-                    # every in-flight fill waits on a chunk nobody will
-                    # write: unreachable with commit-ordered deps, but
-                    # wedging the loop would be worse than degrading —
-                    # roll back their cached-chunk registrations (one
-                    # leaf-first call), drop COW pins, and retire them
-                    # empty + deadlocked
-                    doomed = set(exc.stuck)
-                    inv = [b for b, (s, _) in pending_blocks.items() if s in doomed]
-                    if index is not None and inv:
-                        index.invalidate(inv)
-                    for b in inv:
-                        del pending_blocks[b]
-                    for i in sorted(doomed):
-                        fl, req = fills[i], slots[i]
-                        if fl["cow"] is not None:
-                            pool.free([fl["cow"][0]])
-                        row_tables[i].release()
-                        tables_h[i, :] = self._trash_block
-                        if spec:
-                            if d_row_tables[i].ids:
-                                d_row_tables[i].release()
-                            d_tables_h[i, :] = self._trash_block
-                            d_fills[i] = None
-                        scheduler.finish(req, empty, deadlocked=True)
-                        slots[i], fills[i] = None, None
-                        em_h[i], dn_h[i] = 1, True
-                        yield req.rid, empty
-                    continue
-
-                if spec:
-                    # ---- speculative round: O(2) dispatches ----
-                    # (1) ONE drafter dispatch: drafter prompt chunks for
-                    #     rows still streaming + k greedy proposals for
-                    #     every drafter-ready decode row
-                    # (2) ONE target dispatch: verify descriptors
-                    #     (q_len <= k+1) for speculating rows + target
-                    #     fill chunks in the remaining token-budget lanes
-                    # A decode row whose drafter fill is still streaming
-                    # sits out (inert lane) — scheduling only, greedy
-                    # outputs are position-independent
-                    spec_rows = [i for i in dec_rows if d_fills[i] is None]
-                    d_fill_rows = [i for i in range(B) if d_fills[i] is not None]
-                    draft_ok: list[int] = []
-                    for i in spec_rows:
-                        if d_broken[i]:
-                            continue
-                        if int(bu_h[i] - em_h[i]) < 2 or (
-                            int(self._cache_len_padded - (ln_h[i] + em_h[i] - 1)) < 2
-                        ):
-                            continue  # a 1-token tail can't accept any draft
-                        # +1: the k-loop writes K/V for every proposal
-                        # including d_k at dec_pos + kd (see draft_body)
-                        need = int(ln_h[i] + em_h[i] + kd)
-                        if need > self._cache_len_padded:
-                            continue  # cache tail: draft to trash this round
-                        d_tb = d_row_tables[i]
-                        if d_tb.n_tokens_capacity < need:
-                            n0 = d_tb.n_blocks
-                            if d_tb.extend_to(need):
-                                d_tables_h[i, n0 : d_tb.n_blocks] = d_tb.ids[n0:]
-                            else:
-                                # drafter pool OOM: drop its chain; the row
-                                # keeps verifying garbage drafts (an accept
-                                # requires a target MATCH, so outputs never
-                                # depend on the drafter)
-                                d_broken[i] = True
-                                d_row_tables[i].release()
+                    fill_rows = [i for i in range(B) if fills[i] is not None]
+                    dec_rows = [i for i in active if fills[i] is None and not dn_h[i]]
+                    try:
+                        runnable = resolve_fill_deps(
+                            {i: frozenset(fills[i]["deps"]) for i in fill_rows},
+                            pending_blocks.keys(),
+                        )
+                    except AdmissionDeadlock as exc:
+                        # every in-flight fill waits on a chunk nobody will
+                        # write: unreachable with commit-ordered deps, but
+                        # wedging the loop would be worse than degrading —
+                        # roll back their cached-chunk registrations (one
+                        # leaf-first call), drop COW pins, and retire them
+                        # empty + deadlocked
+                        doomed = set(exc.stuck)
+                        inv = [b for b, (s, _) in pending_blocks.items() if s in doomed]
+                        if index is not None and inv:
+                            index.invalidate(inv)
+                        for b in inv:
+                            del pending_blocks[b]
+                        for i in sorted(doomed):
+                            fl, req = fills[i], slots[i]
+                            if fl["cow"] is not None:
+                                pool.free([fl["cow"][0]])
+                            row_tables[i].release()
+                            tables_h[i, :] = self._trash_block
+                            if spec:
+                                if d_row_tables[i].ids:
+                                    d_row_tables[i].release()
                                 d_tables_h[i, :] = self._trash_block
-                                continue
-                        draft_ok.append(i)
-                    # rows excluded from drafting write into the trash block
-                    d_dec_tab = np.full_like(d_tables_h, self._trash_block)
-                    for i in draft_ok:
-                        d_dec_tab[i] = d_tables_h[i]
-                    dec_pos_h = (ln_h + em_h - 1).astype(np.int32)
-                    drafts = None
-                    if d_fill_rows:
-                        d_tok = np.zeros((B, W), np.int32)
-                        d_qs = np.zeros((B,), np.int32)
-                        d_ql = np.zeros((B,), np.int32)
-                        d_lanes = W
-                        for i in d_fill_rows:
-                            if d_lanes <= 0:
-                                break
-                            fl = d_fills[i]
-                            take = min(fl["length"] - fl["pos"], d_lanes)
-                            d_tok[i, :take] = fl["p"][fl["pos"] : fl["pos"] + take]
-                            d_qs[i] = fl["pos"]
-                            d_ql[i] = take
-                            d_lanes -= take
-                            fl["pos"] += take
-                            if fl["pos"] >= fl["length"]:
                                 d_fills[i] = None
-                        drafts, self._draft_cache = self._draft_rows(
-                            self._draft_params, self._draft_cache,
-                            jnp.asarray(d_tok), jnp.asarray(d_qs), jnp.asarray(d_ql),
-                            cur, jnp.asarray(dec_pos_h), jnp.asarray(d_tables_h),
-                            jnp.asarray(d_dec_tab),
-                        )
-                        # a dispatch that only streams drafter prompt
-                        # chunks is admission overhead (the drafter's
-                        # prefill), not a per-round cost
-                        if draft_ok:
-                            self.draft_dispatches += 1
-                        else:
-                            self.draft_fill_dispatches += 1
-                    elif draft_ok:
-                        drafts, self._draft_cache = self._draft_tokens(
-                            self._draft_params, self._draft_cache, cur,
-                            jnp.asarray(dec_pos_h), jnp.asarray(d_dec_tab),
-                        )
-                        self.draft_dispatches += 1
-                    tok = np.zeros((B, W), np.int32)
-                    q_start_h = np.zeros((B,), np.int32)
-                    q_len_h = np.zeros((B,), np.int32)
-                    is_spec_h = np.zeros((B,), bool)
-                    row_len_h = np.zeros((B,), np.int32)
-                    b_new_h = np.ones((B,), np.int32)
-                    oom = np.zeros((B,), bool)
-                    lanes = W
-                    # verify lanes first (fills absorb the wait), drafted
-                    # rows before un-drafted ones: a round that paid for a
-                    # drafter k-loop always lands >= one q_len >= 2 verify
-                    draft_set = set(draft_ok)
-                    for i in draft_ok + [r for r in spec_rows if r not in draft_set]:
-                        if lanes <= 0:
-                            break
-                        rem = int(bu_h[i] - em_h[i])
-                        space = int(self._cache_len_padded - (ln_h[i] + em_h[i] - 1))
-                        v = min(kd + 1, rem, space, lanes)
-                        if v < 1:
-                            continue
-                        need_tok = min(
-                            ln_h[i] + em_h[i] - 1 + v, self._cache_len_padded
-                        )
-                        tb = row_tables[i]
-                        if tb.n_tokens_capacity < need_tok:
-                            n0 = tb.n_blocks
-                            if tb.extend_to(int(need_tok)):
-                                tables_h[i, n0 : tb.n_blocks] = tb.ids[n0:]
-                            else:
-                                oom[i] = True
-                                dn_h[i] = True
-                                oom_slots.add(i)
-                                continue
-                        is_spec_h[i] = True
-                        q_len_h[i] = v
-                        lanes -= v
-                    for i in runnable:
-                        if lanes <= 0:
-                            break
-                        fl = fills[i]
-                        if fl["cow"] is not None:
-                            src, dst = fl["cow"]
-                            self._cache = self._cow_copy(
-                                self._cache, jnp.int32(src), jnp.int32(dst)
-                            )
-                            pool.free([src])
-                            fl["cow"] = None
-                        take = min(fl["length"] - fl["pos"], lanes)
-                        tok[i, :take] = fl["p"][fl["pos"] : fl["pos"] + take]
-                        q_start_h[i] = fl["pos"]
-                        q_len_h[i] = take
-                        row_len_h[i] = fl["length"]
-                        b_new_h[i] = fl["b_new"]
-                        lanes -= take
-                        fl["pos"] += take
-                        mine = [
-                            b for b, (s, e) in pending_blocks.items()
-                            if s == i and e <= fl["pos"]
-                        ]
-                        for b in mine:
-                            del pending_blocks[b]
-                        if fl["pos"] >= fl["length"]:
-                            fills[i] = None
-                    if oom.any():
-                        done = jnp.logical_or(done, jnp.asarray(oom))
-                    if is_spec_h.any() or q_len_h.any():
-                        em_before = em_h.copy()
-                        (self._cache, cur, lengths, emitted, done, budget, out) = (
-                            self._spec_mixed_rows(
-                                self.params, self._cache, cur, lengths, emitted,
-                                done, budget, out,
-                                jnp.asarray(tok), jnp.asarray(q_start_h),
-                                jnp.asarray(q_len_h), jnp.asarray(is_spec_h),
-                                drafts if drafts is not None
-                                else jnp.zeros((B, kd), jnp.int32),
-                                jnp.asarray(row_len_h), jnp.asarray(b_new_h),
-                                jnp.asarray(tables_h),
-                            )
-                        )
-                        self.mixed_dispatches += 1
-                        steps += 1
-                        em_h, dn_h = np.array(emitted), np.array(done)
-                        if is_spec_h.any():
-                            committed = em_h[is_spec_h] - em_before[is_spec_h]
-                            self.spec_tokens_emitted += int(committed.sum())
-                            self.spec_tokens_proposed += int(
-                                (q_len_h[is_spec_h] - 1).sum()
-                            )
-                            self.spec_tokens_accepted += int(
-                                np.maximum(committed - 1, 0).sum()
-                            )
-                            if (q_len_h[is_spec_h] > 1).any():
-                                self.spec_rounds += 1
-                elif runnable:
-                    # ---- ONE mixed dispatch: decode lanes + fill chunks ----
-                    tok = np.zeros((B, W), np.int32)
-                    q_start_h = np.zeros((B,), np.int32)
-                    q_len_h = np.zeros((B,), np.int32)
-                    is_dec = np.zeros((B,), bool)
-                    row_len_h = np.zeros((B,), np.int32)
-                    b_new_h = np.ones((B,), np.int32)
-                    oom = np.zeros((B,), bool)
-                    lanes = W
-                    for i in dec_rows:  # decode first: fills absorb the wait
-                        if lanes <= 0:
-                            break
-                        need_tok = min(
-                            ln_h[i] + min(em_h[i] + 1, bu_h[i]) - 1,
-                            self._cache_len_padded,
-                        )
-                        tb = row_tables[i]
-                        if tb.n_tokens_capacity < need_tok:
-                            n0 = tb.n_blocks
-                            if tb.extend_to(int(need_tok)):
-                                tables_h[i, n0 : tb.n_blocks] = tb.ids[n0:]
-                            else:
-                                oom[i] = True
-                                dn_h[i] = True
-                                oom_slots.add(i)
-                                continue
-                        is_dec[i] = True
-                        q_len_h[i] = 1
-                        lanes -= 1
-                    for i in runnable:
-                        if lanes <= 0:
-                            break
-                        fl = fills[i]
-                        if fl["cow"] is not None:
-                            # boundary copy must precede this fill's writes;
-                            # the copy consumes the source's cache VALUE, so
-                            # commit's pin drops immediately after dispatch
-                            src, dst = fl["cow"]
-                            self._cache = self._cow_copy(
-                                self._cache, jnp.int32(src), jnp.int32(dst)
-                            )
-                            pool.free([src])
-                            fl["cow"] = None
-                        take = min(fl["length"] - fl["pos"], lanes)
-                        tok[i, :take] = fl["p"][fl["pos"] : fl["pos"] + take]
-                        q_start_h[i] = fl["pos"]
-                        q_len_h[i] = take
-                        row_len_h[i] = fl["length"]
-                        b_new_h[i] = fl["b_new"]
-                        lanes -= take
-                        fl["pos"] += take
-                        # chunks this dispatch materializes become matchable
-                        mine = [
-                            b for b, (s, e) in pending_blocks.items()
-                            if s == i and e <= fl["pos"]
-                        ]
-                        for b in mine:
-                            del pending_blocks[b]
-                        if fl["pos"] >= fl["length"]:
-                            fills[i] = None  # completes in this dispatch
-                    if oom.any():
-                        done = jnp.logical_or(done, jnp.asarray(oom))
-                    (self._cache, cur, lengths, emitted, done, budget, out) = self._mixed_rows(
-                        self.params, self._cache, cur, lengths, emitted, done, budget, out,
-                        jnp.asarray(tok), jnp.asarray(q_start_h), jnp.asarray(q_len_h),
-                        jnp.asarray(is_dec), jnp.asarray(row_len_h),
-                        jnp.asarray(b_new_h), jnp.asarray(tables_h),
-                    )
-                    self.mixed_dispatches += 1
-                    steps += 1
-                    em_h, dn_h = np.array(emitted), np.array(done)
-                elif dec_rows:
-                    # no fill in flight: fused multi-step decode, one dispatch
-                    remaining = [int(bu_h[i] - em_h[i]) for i in dec_rows]
-                    n = max(1, min(max(remaining), scfg.sched_chunk))
-                    oom = np.zeros((B,), bool)
-                    for i in dec_rows:
-                        need_tok = min(
-                            ln_h[i] + min(em_h[i] + n, bu_h[i]) - 1,
-                            self._cache_len_padded,
-                        )
-                        tb = row_tables[i]
-                        if tb.n_tokens_capacity >= need_tok:
-                            continue
-                        n0 = tb.n_blocks
-                        if tb.extend_to(int(need_tok)):
-                            tables_h[i, n0 : tb.n_blocks] = tb.ids[n0:]
-                        else:
-                            oom[i] = True
-                            dn_h[i] = True
-                            oom_slots.add(i)
-                    if oom.any():
-                        done = jnp.logical_or(done, jnp.asarray(oom))
-                    self._cache, cur, emitted, done, out = self._decode_chunk(
-                        self.params, self._cache, cur, lengths, emitted, done, budget, out,
-                        jnp.int32(n), jnp.asarray(tables_h),
-                    )
-                    self.decode_dispatches += 1
-                    steps += 1
-                    em_h, dn_h = np.array(emitted), np.array(done)
+                            scheduler.finish(req, empty, deadlocked=True)
+                            slots[i], fills[i] = None, None
+                            em_h[i], dn_h[i] = 1, True
+                            with step.suspended("engine.yield"):
+                                yield req.rid, empty
+                        continue
 
-                retired = [i for i in active if dn_h[i] and fills[i] is None and slots[i] is not None]
-                if retired:
-                    out_h = np.asarray(out)
-                    for i in retired:
-                        req = slots[i]
-                        ans = out_h[i, : int(em_h[i])].copy()
-                        scheduler.finish(req, ans, truncated=i in oom_slots)
-                        oom_slots.discard(i)
-                        slots[i] = None
-                        row_tables[i].release()
-                        tables_h[i, :] = self._trash_block
-                        if spec:
-                            if d_row_tables[i].ids:
-                                d_row_tables[i].release()
-                            d_tables_h[i, :] = self._trash_block
-                            d_fills[i] = None
-                            d_broken[i] = False
-                        yield req.rid, ans
+                    if spec:
+                        # ---- speculative round: O(2) dispatches ----
+                        # (1) ONE drafter dispatch: drafter prompt chunks for
+                        #     rows still streaming + k greedy proposals for
+                        #     every drafter-ready decode row
+                        # (2) ONE target dispatch: verify descriptors
+                        #     (q_len <= k+1) for speculating rows + target
+                        #     fill chunks in the remaining token-budget lanes
+                        # A decode row whose drafter fill is still streaming
+                        # sits out (inert lane) — scheduling only, greedy
+                        # outputs are position-independent
+                        spec_rows = [i for i in dec_rows if d_fills[i] is None]
+                        d_fill_rows = [i for i in range(B) if d_fills[i] is not None]
+                        draft_ok: list[int] = []
+                        for i in spec_rows:
+                            if d_broken[i]:
+                                continue
+                            if int(bu_h[i] - em_h[i]) < 2 or (
+                                int(self._cache_len_padded - (ln_h[i] + em_h[i] - 1)) < 2
+                            ):
+                                continue  # a 1-token tail can't accept any draft
+                            # +1: the k-loop writes K/V for every proposal
+                            # including d_k at dec_pos + kd (see draft_body)
+                            need = int(ln_h[i] + em_h[i] + kd)
+                            if need > self._cache_len_padded:
+                                continue  # cache tail: draft to trash this round
+                            d_tb = d_row_tables[i]
+                            if d_tb.n_tokens_capacity < need:
+                                n0 = d_tb.n_blocks
+                                if d_tb.extend_to(need):
+                                    d_tables_h[i, n0 : d_tb.n_blocks] = d_tb.ids[n0:]
+                                else:
+                                    # drafter pool OOM: drop its chain; the row
+                                    # keeps verifying garbage drafts (an accept
+                                    # requires a target MATCH, so outputs never
+                                    # depend on the drafter)
+                                    d_broken[i] = True
+                                    d_row_tables[i].release()
+                                    d_tables_h[i, :] = self._trash_block
+                                    continue
+                            draft_ok.append(i)
+                        # rows excluded from drafting write into the trash block
+                        d_dec_tab = np.full_like(d_tables_h, self._trash_block)
+                        for i in draft_ok:
+                            d_dec_tab[i] = d_tables_h[i]
+                        dec_pos_h = (ln_h + em_h - 1).astype(np.int32)
+                        drafts = None
+                        if d_fill_rows:
+                            d_tok = np.zeros((B, W), np.int32)
+                            d_qs = np.zeros((B,), np.int32)
+                            d_ql = np.zeros((B,), np.int32)
+                            d_lanes = W
+                            for i in d_fill_rows:
+                                if d_lanes <= 0:
+                                    break
+                                fl = d_fills[i]
+                                take = min(fl["length"] - fl["pos"], d_lanes)
+                                d_tok[i, :take] = fl["p"][fl["pos"] : fl["pos"] + take]
+                                d_qs[i] = fl["pos"]
+                                d_ql[i] = take
+                                d_lanes -= take
+                                fl["pos"] += take
+                                if fl["pos"] >= fl["length"]:
+                                    d_fills[i] = None
+                            with tracing.span("engine.launch", kind="draft"):
+                                drafts, self._draft_cache = self._draft_rows(
+                                    self._draft_params, self._draft_cache,
+                                    jnp.asarray(d_tok), jnp.asarray(d_qs), jnp.asarray(d_ql),
+                                    cur, jnp.asarray(dec_pos_h), jnp.asarray(d_tables_h),
+                                    jnp.asarray(d_dec_tab),
+                                )
+                            # a dispatch that only streams drafter prompt
+                            # chunks is admission overhead (the drafter's
+                            # prefill), not a per-round cost
+                            if draft_ok:
+                                self.draft_dispatches += 1
+                            else:
+                                self.draft_fill_dispatches += 1
+                        elif draft_ok:
+                            with tracing.span("engine.launch", kind="draft"):
+                                drafts, self._draft_cache = self._draft_tokens(
+                                    self._draft_params, self._draft_cache, cur,
+                                    jnp.asarray(dec_pos_h), jnp.asarray(d_dec_tab),
+                                )
+                            self.draft_dispatches += 1
+                        tok = np.zeros((B, W), np.int32)
+                        q_start_h = np.zeros((B,), np.int32)
+                        q_len_h = np.zeros((B,), np.int32)
+                        is_spec_h = np.zeros((B,), bool)
+                        row_len_h = np.zeros((B,), np.int32)
+                        b_new_h = np.ones((B,), np.int32)
+                        oom = np.zeros((B,), bool)
+                        lanes = W
+                        # verify lanes first (fills absorb the wait), drafted
+                        # rows before un-drafted ones: a round that paid for a
+                        # drafter k-loop always lands >= one q_len >= 2 verify
+                        draft_set = set(draft_ok)
+                        for i in draft_ok + [r for r in spec_rows if r not in draft_set]:
+                            if lanes <= 0:
+                                break
+                            rem = int(bu_h[i] - em_h[i])
+                            space = int(self._cache_len_padded - (ln_h[i] + em_h[i] - 1))
+                            v = min(kd + 1, rem, space, lanes)
+                            if v < 1:
+                                continue
+                            need_tok = min(
+                                ln_h[i] + em_h[i] - 1 + v, self._cache_len_padded
+                            )
+                            tb = row_tables[i]
+                            if tb.n_tokens_capacity < need_tok:
+                                n0 = tb.n_blocks
+                                if tb.extend_to(int(need_tok)):
+                                    tables_h[i, n0 : tb.n_blocks] = tb.ids[n0:]
+                                else:
+                                    oom[i] = True
+                                    dn_h[i] = True
+                                    oom_slots.add(i)
+                                    continue
+                            is_spec_h[i] = True
+                            q_len_h[i] = v
+                            lanes -= v
+                        for i in runnable:
+                            if lanes <= 0:
+                                break
+                            fl = fills[i]
+                            if fl["cow"] is not None:
+                                src, dst = fl["cow"]
+                                self._cache = self._cow_copy(
+                                    self._cache, jnp.int32(src), jnp.int32(dst)
+                                )
+                                pool.free([src])
+                                fl["cow"] = None
+                            take = min(fl["length"] - fl["pos"], lanes)
+                            tok[i, :take] = fl["p"][fl["pos"] : fl["pos"] + take]
+                            q_start_h[i] = fl["pos"]
+                            q_len_h[i] = take
+                            row_len_h[i] = fl["length"]
+                            b_new_h[i] = fl["b_new"]
+                            lanes -= take
+                            fl["pos"] += take
+                            mine = [
+                                b for b, (s, e) in pending_blocks.items()
+                                if s == i and e <= fl["pos"]
+                            ]
+                            for b in mine:
+                                del pending_blocks[b]
+                            if fl["pos"] >= fl["length"]:
+                                fills[i] = None
+                        if oom.any():
+                            done = jnp.logical_or(done, jnp.asarray(oom))
+                        if is_spec_h.any() or q_len_h.any():
+                            em_before = em_h.copy()
+                            with tracing.span("engine.dispatch", kind="spec") as disp:
+                                if disp.recording:
+                                    disp.attrs.update(self._geometry(
+                                        slots, ln_h, em_h, dn_h, q_start=q_start_h, q_len=q_len_h,
+                                        is_spec=is_spec_h, row_len=row_len_h))
+                                with tracing.span("engine.launch"):
+                                    (self._cache, cur, lengths, emitted, done, budget, out) = (
+                                        self._spec_mixed_rows(
+                                            self.params, self._cache, cur, lengths, emitted,
+                                            done, budget, out,
+                                            jnp.asarray(tok), jnp.asarray(q_start_h),
+                                            jnp.asarray(q_len_h), jnp.asarray(is_spec_h),
+                                            drafts if drafts is not None
+                                            else jnp.zeros((B, kd), jnp.int32),
+                                            jnp.asarray(row_len_h), jnp.asarray(b_new_h),
+                                            jnp.asarray(tables_h),
+                                        )
+                                    )
+                                self.mixed_dispatches += 1
+                                steps += 1
+                                with tracing.span("engine.readback"):
+                                    em_h, dn_h = np.array(emitted), np.array(done)
+                            _stamp_first_tokens(slots, em_h, fills)
+                            if is_spec_h.any():
+                                committed = em_h[is_spec_h] - em_before[is_spec_h]
+                                self.spec_tokens_emitted += int(committed.sum())
+                                self.spec_tokens_proposed += int(
+                                    (q_len_h[is_spec_h] - 1).sum()
+                                )
+                                self.spec_tokens_accepted += int(
+                                    np.maximum(committed - 1, 0).sum()
+                                )
+                                if (q_len_h[is_spec_h] > 1).any():
+                                    self.spec_rounds += 1
+                    elif runnable:
+                        # ---- ONE mixed dispatch: decode lanes + fill chunks ----
+                        tok = np.zeros((B, W), np.int32)
+                        q_start_h = np.zeros((B,), np.int32)
+                        q_len_h = np.zeros((B,), np.int32)
+                        is_dec = np.zeros((B,), bool)
+                        row_len_h = np.zeros((B,), np.int32)
+                        b_new_h = np.ones((B,), np.int32)
+                        oom = np.zeros((B,), bool)
+                        lanes = W
+                        for i in dec_rows:  # decode first: fills absorb the wait
+                            if lanes <= 0:
+                                break
+                            need_tok = min(
+                                ln_h[i] + min(em_h[i] + 1, bu_h[i]) - 1,
+                                self._cache_len_padded,
+                            )
+                            tb = row_tables[i]
+                            if tb.n_tokens_capacity < need_tok:
+                                n0 = tb.n_blocks
+                                if tb.extend_to(int(need_tok)):
+                                    tables_h[i, n0 : tb.n_blocks] = tb.ids[n0:]
+                                else:
+                                    oom[i] = True
+                                    dn_h[i] = True
+                                    oom_slots.add(i)
+                                    continue
+                            is_dec[i] = True
+                            q_len_h[i] = 1
+                            lanes -= 1
+                        for i in runnable:
+                            if lanes <= 0:
+                                break
+                            fl = fills[i]
+                            if fl["cow"] is not None:
+                                # boundary copy must precede this fill's writes;
+                                # the copy consumes the source's cache VALUE, so
+                                # commit's pin drops immediately after dispatch
+                                src, dst = fl["cow"]
+                                self._cache = self._cow_copy(
+                                    self._cache, jnp.int32(src), jnp.int32(dst)
+                                )
+                                pool.free([src])
+                                fl["cow"] = None
+                            take = min(fl["length"] - fl["pos"], lanes)
+                            tok[i, :take] = fl["p"][fl["pos"] : fl["pos"] + take]
+                            q_start_h[i] = fl["pos"]
+                            q_len_h[i] = take
+                            row_len_h[i] = fl["length"]
+                            b_new_h[i] = fl["b_new"]
+                            lanes -= take
+                            fl["pos"] += take
+                            # chunks this dispatch materializes become matchable
+                            mine = [
+                                b for b, (s, e) in pending_blocks.items()
+                                if s == i and e <= fl["pos"]
+                            ]
+                            for b in mine:
+                                del pending_blocks[b]
+                            if fl["pos"] >= fl["length"]:
+                                fills[i] = None  # completes in this dispatch
+                        if oom.any():
+                            done = jnp.logical_or(done, jnp.asarray(oom))
+                        with tracing.span("engine.dispatch", kind="mixed") as disp:
+                            if disp.recording:
+                                disp.attrs.update(self._geometry(
+                                    slots, ln_h, em_h, dn_h, q_start=q_start_h, q_len=q_len_h,
+                                    is_decode=is_dec, row_len=row_len_h))
+                            args = (jnp.asarray(tok), jnp.asarray(q_start_h), jnp.asarray(q_len_h),
+                                    jnp.asarray(is_dec), jnp.asarray(row_len_h),
+                                    jnp.asarray(b_new_h), jnp.asarray(tables_h))
+                            with tracing.span("engine.launch"):
+                                (self._cache, cur, lengths, emitted, done, budget, out) = (
+                                    self._mixed_rows(
+                                        self.params, self._cache, cur, lengths, emitted, done,
+                                        budget, out, *args,
+                                    )
+                                )
+                            self.mixed_dispatches += 1
+                            steps += 1
+                            with tracing.span("engine.readback"):
+                                em_h, dn_h = np.array(emitted), np.array(done)
+                        _stamp_first_tokens(slots, em_h, fills)
+                    elif dec_rows:
+                        # no fill in flight: fused multi-step decode, one dispatch
+                        remaining = [int(bu_h[i] - em_h[i]) for i in dec_rows]
+                        n = max(1, min(max(remaining), scfg.sched_chunk))
+                        oom = np.zeros((B,), bool)
+                        for i in dec_rows:
+                            need_tok = min(
+                                ln_h[i] + min(em_h[i] + n, bu_h[i]) - 1,
+                                self._cache_len_padded,
+                            )
+                            tb = row_tables[i]
+                            if tb.n_tokens_capacity >= need_tok:
+                                continue
+                            n0 = tb.n_blocks
+                            if tb.extend_to(int(need_tok)):
+                                tables_h[i, n0 : tb.n_blocks] = tb.ids[n0:]
+                            else:
+                                oom[i] = True
+                                dn_h[i] = True
+                                oom_slots.add(i)
+                        if oom.any():
+                            done = jnp.logical_or(done, jnp.asarray(oom))
+                        with tracing.span("engine.dispatch", kind="decode") as disp:
+                            if disp.recording:
+                                disp.attrs.update(
+                                    self._geometry(slots, ln_h, em_h, dn_h, n_steps=n)
+                                )
+                            args = (jnp.int32(n), jnp.asarray(tables_h))
+                            with tracing.span("engine.launch"):
+                                self._cache, cur, emitted, done, out = self._decode_chunk(
+                                    self.params, self._cache, cur, lengths, emitted, done,
+                                    budget, out, *args,
+                                )
+                            self.decode_dispatches += 1
+                            steps += 1
+                            with tracing.span("engine.readback"):
+                                em_h, dn_h = np.array(emitted), np.array(done)
+                            if disp.recording:
+                                disp.attrs["emitted_after"] = em_h.copy()
+                        _stamp_first_tokens(slots, em_h, fills)
+
+                    retired = [
+                        i for i in active
+                        if dn_h[i] and fills[i] is None and slots[i] is not None
+                    ]
+                    answered = []
+                    if retired:
+                        with tracing.span("engine.retire", rows=len(retired)):
+                            out_h = np.asarray(out)
+                            for i in retired:
+                                req = slots[i]
+                                ans = out_h[i, : int(em_h[i])].copy()
+                                scheduler.finish(req, ans, truncated=i in oom_slots)
+                                oom_slots.discard(i)
+                                slots[i] = None
+                                row_tables[i].release()
+                                tables_h[i, :] = self._trash_block
+                                if spec:
+                                    if d_row_tables[i].ids:
+                                        d_row_tables[i].release()
+                                    d_tables_h[i, :] = self._trash_block
+                                    d_fills[i] = None
+                                    d_broken[i] = False
+                                answered.append((req.rid, ans))
+                    for rid, ans in answered:
+                        # the consumer runs while the device has nothing queued
+                        with step.suspended("engine.yield"):
+                            yield rid, ans
         finally:
             # the pool/index outlive this call, so an abandoned stream must
             # not leak owned blocks or half-materialized chunk registrations

@@ -71,6 +71,8 @@ from typing import Any
 
 import numpy as np
 
+from repro.runtime import tracing
+
 
 @dataclasses.dataclass
 class Request:
@@ -83,6 +85,9 @@ class Request:
     submitted_at: float = 0.0  # actual submit time: the expiry clock
     anchor_t0: float | None = None  # optional upstream anchor for latency_s only
     started_at: float | None = None  # slot admission time
+    # read-back that brought the first answer token (``finished_at`` for
+    # an answer that never got one); set for every finished request
+    first_token_at: float | None = None
     finished_at: float | None = None
     answer: np.ndarray | None = None
     status: str = "queued"  # queued | active | done | expired
@@ -96,8 +101,20 @@ class Request:
     def latency_s(self) -> float | None:
         if self.finished_at is None:
             return None
-        start = self.submitted_at if self.anchor_t0 is None else self.anchor_t0
-        return self.finished_at - start
+        return self.finished_at - self.origin
+
+    @property
+    def ttft_s(self) -> float | None:
+        """Time to the first answer token, from the same origin as
+        ``latency_s``."""
+        if self.first_token_at is None:
+            return None
+        return self.first_token_at - self.origin
+
+    @property
+    def origin(self) -> float:
+        """Zero of the latency clocks: the upstream anchor, else submit."""
+        return self.submitted_at if self.anchor_t0 is None else self.anchor_t0
 
 
 def _broadcast(values, n: int, what: str) -> list:
@@ -121,7 +138,8 @@ def _broadcast(values, n: int, what: str) -> list:
 
 
 def _percentiles(reqs) -> dict:
-    """n_done/expiry/flag counts + p50/p95/mean over a request set."""
+    """n_done/expiry/flag counts + p50/p95/mean latency and p50/p95 time
+    to first token over a request set."""
     done = [r for r in reqs if r.status == "done"]
     out = {
         "n_done": len(done),
@@ -135,6 +153,10 @@ def _percentiles(reqs) -> dict:
         out["p50_s"] = float(np.percentile(arr, 50))
         out["p95_s"] = float(np.percentile(arr, 95))
         out["mean_s"] = float(arr.mean())
+    ttft = [r.ttft_s for r in done if r.ttft_s is not None]
+    if ttft:
+        out["ttft_p50_s"] = float(np.percentile(ttft, 50))
+        out["ttft_p95_s"] = float(np.percentile(ttft, 95))
     return out
 
 
@@ -391,7 +413,14 @@ class Scheduler:
         req.truncated = truncated
         req.deadlocked = deadlocked
         req.finished_at = time.monotonic()
+        if req.first_token_at is None:
+            req.first_token_at = req.finished_at
         req.answer = np.asarray(answer)
+        if req.started_at is not None and tracing.enabled():
+            ns = [int(t * 1e9) for t in (req.started_at, req.first_token_at, req.finished_at)]
+            attrs = dict(rid=req.rid, tag=req.tag, tokens=int(req.answer.size))
+            tracing.record("req.prefill", ns[0], ns[1], **attrs)
+            tracing.record("req.decode", ns[1], ns[2], **attrs)
         with self._cond:
             self.results[req.rid] = req
             self._cond.notify_all()  # wake drain() waiters
@@ -554,8 +583,9 @@ class Scheduler:
         return tenants
 
     def latency_stats(self) -> dict:
-        """p50/p95/mean submit->finish latency plus occupancy, prefix-
-        cache, dispatch, and per-tenant gauges.
+        """p50/p95/mean submit->finish latency and p50/p95 time to first
+        token (``ttft_*``) plus occupancy, prefix-cache, dispatch, and
+        per-tenant gauges.
 
         Top-level numbers cover the current WINDOW (since the last
         ``begin_window()``; the scheduler's whole life if never called).

@@ -1,6 +1,6 @@
 import os
 
-# smoke tests and benches must see 1 device (the dry-run sets 512 itself)
+# smoke tests and benches run on the CPU; the multi-device tests set their own devices
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
