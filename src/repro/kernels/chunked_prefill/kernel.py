@@ -12,7 +12,10 @@ rows (q_len == 1) all run in the same grid.
 
 Grid (R, KV, n_t); all W lanes x G group heads of a (row, kv-head) pair
 ride in one (W*G, BS) logits block so the MXU sees a real tile even when
-most rows are decodes.
+most rows are decodes.  Each row walks only its live blocks ``0 ..
+cdiv(kv_len, BS) - 1`` (none when ``q_len == 0``): the K/V index maps
+clamp to the last live block, so later steps copy nothing, and the body
+skips them, so the kernel's time follows the live KV, not the table.
 """
 from __future__ import annotations
 
@@ -40,7 +43,12 @@ def _mixed_kernel(desc_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     logits are NEG_INF, exp(NEG_INF - m) == +0.0 whenever any position
     is live), but a fully-masked lane keeps m == NEG_INF so the exp
     would give exp(0) == 1 per position — zeroing makes dead lanes
-    contribute l == 0 and output exactly 0 instead."""
+    contribute l == 0 and output exactly 0 instead.
+
+    Blocks at or past ``kv_len``, and every block of a ``q_len == 0``
+    row, are skipped: there each lane's update is the identity (``p ==
+    0``, ``alpha == 1``), and the clamped index maps never copy the block
+    the table names, so its contents are never read."""
     ri = pl.program_id(0)
     tj = pl.program_id(2)
 
@@ -50,27 +58,29 @@ def _mixed_kernel(desc_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)  # (W*G, dh)
-    k = k_ref[0, 0].astype(jnp.float32)  # (BS, dh)
-    v = v_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (W*G, BS)
-    lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // g
-    kpos = tj * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    qpos = desc_ref[ri, 1] + lane
-    valid = (kpos <= qpos) & (kpos < desc_ref[ri, 3]) & (lane < desc_ref[ri, 2])
-    s = jnp.where(valid, s, NEG_INF)
+    @pl.when((tj * bs < desc_ref[ri, 3]) & (desc_ref[ri, 2] > 0))
+    def _update():
+        q = q_ref[0, 0].astype(jnp.float32)  # (W*G, dh)
+        k = k_ref[0, 0].astype(jnp.float32)  # (BS, dh)
+        v = v_ref[0, 0].astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # (W*G, BS)
+        lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // g
+        kpos = tj * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        qpos = desc_ref[ri, 1] + lane
+        valid = (kpos <= qpos) & (kpos < desc_ref[ri, 3]) & (lane < desc_ref[ri, 2])
+        s = jnp.where(valid, s, NEG_INF)
 
-    m_prev, l_prev = m_scr[...], l_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_prev * alpha + p.sum(-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(
-        p, v, preferred_element_type=jnp.float32
-    )
-    m_scr[...] = m_new
+        m_prev, l_prev = m_scr[...], l_scr[...]
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_prev * alpha + p.sum(-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(
+            p, v, preferred_element_type=jnp.float32
+        )
+        m_scr[...] = m_new
 
     @pl.when(tj == n_t - 1)
     def _finish():
@@ -103,13 +113,19 @@ def mixed_prefill_attention_pallas(
     vt = v_pool.transpose(0, 2, 1, 3)
     grid = (r, kv, n_t)
 
+    def kv_map(ri, ki, tj, dsc, tbl):
+        # past a row's last live block the index stops changing, so the
+        # pipeline issues no further copy; a q_len == 0 row sits on block 0
+        last = jnp.where(dsc[ri, 2] > 0, jnp.maximum((dsc[ri, 3] + bs - 1) // bs - 1, 0), 0)
+        return tbl[dsc[ri, 0], jnp.minimum(tj, last)], ki, 0, 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # desc, block_tables
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, w * g, dh), lambda ri, ki, tj, dsc, tbl: (ri, ki, 0, 0)),
-            pl.BlockSpec((1, 1, bs, dh), lambda ri, ki, tj, dsc, tbl: (tbl[dsc[ri, 0], tj], ki, 0, 0)),
-            pl.BlockSpec((1, 1, bs, dh), lambda ri, ki, tj, dsc, tbl: (tbl[dsc[ri, 0], tj], ki, 0, 0)),
+            pl.BlockSpec((1, 1, bs, dh), kv_map),
+            pl.BlockSpec((1, 1, bs, dh), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, w * g, dh), lambda ri, ki, tj, dsc, tbl: (ri, ki, 0, 0)),

@@ -1669,6 +1669,11 @@ class ServeEngine:
                                 disp.attrs.update(self._geometry(
                                     slots, ln_h, em_h, dn_h, q_start=q_start_h, q_len=q_len_h,
                                     is_decode=is_dec, row_len=row_len_h))
+                                # the attention kernel's KV walk: live blocks vs its grid
+                                kv_end = np.where(is_dec, ln_h + em_h - 1, q_start_h) + q_len_h
+                                disp.attrs.update(
+                                    kv_blocks_live=int((-(-kv_end[q_len_h > 0] // bs)).sum()),
+                                    kv_blocks_grid=B * tables_h.shape[1])
                             args = (jnp.asarray(tok), jnp.asarray(q_start_h), jnp.asarray(q_len_h),
                                     jnp.asarray(is_dec), jnp.asarray(row_len_h),
                                     jnp.asarray(b_new_h), jnp.asarray(tables_h))

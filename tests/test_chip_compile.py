@@ -72,9 +72,13 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("w", [1, 256])
-def test_mixed_prefill_kernel_compiles_for_v5e(one_chip, w):
-    b, n_t, n_pool = 8, 33, 8 * 33 + 1
+@pytest.mark.parametrize("w,n_t", [
+    pytest.param(1, 33, id="1"),
+    pytest.param(256, 33, id="256"),
+    pytest.param(256, 160, id="256-160"),  # the benchmark cells' table width
+])
+def test_mixed_prefill_kernel_compiles_for_v5e(one_chip, w, n_t):
+    b, n_pool = 8, 8 * n_t + 1
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     text = _compiled_text(
         mixed_prefill_attention_pallas,

@@ -174,10 +174,15 @@ def _mixed_oracle_np(q, kp, vp, tables, desc):
     return out
 
 
-def _rand_mixed_case(rng, b, w, h, kv, dh, bs, n_t):
+MIXED_KINDS = ("decode", "cold", "warm", "boundary", "dead")
+
+
+def _rand_mixed_case(rng, b, w, h, kv, dh, bs, n_t, kinds=MIXED_KINDS):
     """Random pool + disjoint shuffled tables + a descriptor mix covering
     decode rows, cold/warm fill chunks, a COW-style boundary row, and a
-    zero-length row when b allows."""
+    zero-length row when b allows; row i takes ``kinds[i % len(kinds)]``,
+    where ``edge`` ends a fill on a block boundary and ``full`` fills the
+    whole table."""
     n_pool = b * n_t + 1
     kk = jax.random.PRNGKey(rng.integers(2**31))
     q = jax.random.normal(kk, (b, w, h, dh))
@@ -189,7 +194,7 @@ def _rand_mixed_case(rng, b, w, h, kv, dh, bs, n_t):
     cap = n_t * bs
     desc = np.zeros((b, 4), np.int32)
     for i in range(b):
-        kind = ["decode", "cold", "warm", "boundary", "dead"][i % 5]
+        kind = kinds[i % len(kinds)]
         if kind == "decode":  # 1 fresh token at the tip of a live cache
             q0 = int(rng.integers(0, cap))
             desc[i] = (i, q0, 1, q0 + 1)
@@ -203,6 +208,10 @@ def _rand_mixed_case(rng, b, w, h, kv, dh, bs, n_t):
         elif kind == "boundary":  # full-prefix COW hit: single suffix lane
             kl = int(rng.integers(1, cap + 1))
             desc[i] = (i, kl - 1, 1, kl)
+        elif kind in ("edge", "full"):  # kv_len a whole number of blocks
+            kl = cap if kind == "full" else bs * int(rng.integers(1, n_t + 1))
+            ql = int(rng.integers(1, min(w, kl) + 1))
+            desc[i] = (i, kl - ql, ql, kl)
         else:  # zero-length suffix: inert row, must output exact 0
             desc[i] = (i, int(rng.integers(0, cap)), 0, int(rng.integers(1, cap)))
     return q, kp, vp, tables, jnp.asarray(desc)
@@ -272,6 +281,36 @@ def test_mixed_prefill_trash_blocks_never_leak():
     vp2 = vp.at[trash].set(1e4).at[1, 4:].set(-1e4).at[3, 3:].set(-1e4)
     poisoned = mixed_prefill_attention_ref(q, kp2, vp2, tables, desc)
     assert_allclose(np.asarray(base), np.asarray(poisoned), rtol=0, atol=0)
+
+
+@pytest.mark.parametrize(
+    "kinds",
+    [MIXED_KINDS] + [(k, "dead") for k in MIXED_KINDS[:4] + ("edge", "full")] + [("dead",)],
+    ids=["mixed", "decode", "cold", "warm", "boundary", "block-edge", "full-table", "all-dead"],
+)
+def test_mixed_prefill_kernel_never_reads_past_kv_len(kinds):
+    """Every pool block the table maps past a row's live blocks (``0 ..
+    cdiv(kv_len, bs) - 1``), and every block of a ``q_len == 0`` row, is
+    NaN: a kernel that still ran its update on one would carry the NaN
+    into the output (``0 * NaN`` in ``p @ v``), so the output must be
+    bit-identical to the clean run."""
+    from repro.kernels.chunked_prefill.kernel import mixed_prefill_attention_pallas
+
+    b, w, h, kv, dh, bs, n_t = 5, 4, 4, 2, 16, 4, 4
+    rng = np.random.default_rng(len(kinds) * 7 + sum(map(len, kinds)))
+    q, kp, vp, tables, desc = _rand_mixed_case(rng, b, w, h, kv, dh, bs, n_t, kinds)
+    d, tb = np.asarray(desc), np.asarray(tables)
+    dead = []
+    for slot, _, q_len, kv_len in d:
+        live = -(-kv_len // bs) if q_len > 0 else 0
+        dead.extend(tb[slot, live:])
+    dead = np.asarray(dead, np.int32)
+    assert dead.size
+    kp2, vp2 = kp.at[dead].set(np.nan), vp.at[dead].set(np.nan)
+    clean = np.asarray(mixed_prefill_attention_pallas(q, kp, vp, tables, desc))
+    poisoned = np.asarray(mixed_prefill_attention_pallas(q, kp2, vp2, tables, desc))
+    assert np.isfinite(clean).all()
+    assert np.array_equal(clean, poisoned)
 
 
 def test_mixed_prefill_verify_rows_match_per_lane_decode():

@@ -173,6 +173,29 @@ def test_first_token_lies_between_admission_and_finish(monkeypatch, path):
             assert len(carried) >= 2
 
 
+def test_a_mixed_dispatch_counts_the_kv_blocks_its_attention_walks(monkeypatch):
+    """``kv_blocks_live`` sums ``cdiv(q_start + q_len, block_size)`` over
+    the rows with ``q_len > 0`` (a decode row's ``q_start`` is ``lengths +
+    emitted - 1``); ``kv_blocks_grid`` is ``max_batch`` x the table width."""
+    eng = make_fake_engine(monkeypatch, max_batch=3, max_new_tokens=4, paged=True,
+                           block_size=4, token_budget=4)
+    sched = Scheduler()
+    sched.submit_many([prompt_ending(30, length=7), prompt_ending(40, length=8)], 4)
+    with tracing.recording():
+        eng.serve(sched)
+    mixed = [r.attrs for r in by_name(tracing.spans(), "engine.dispatch")
+             if r.attrs["kind"] == "mixed"]
+    # the first step with a decode row: slot 0 decodes its first answer token
+    # at position 7 (kv 8: 2 blocks), slot 1 fills prompt positions 1-3 of 8
+    # (kv 4: 1 block), slot 2 is empty (no block)
+    d = next(a for a in mixed if a["is_decode"].any())
+    assert list(d["is_decode"]) == [True, False, False]
+    assert list(d["q_len"]) == [1, 3, 0] and d["rids"][2] == -1
+    assert d["q_start"][1] + d["q_len"][1] < d["row_len"][1]
+    assert d["kv_blocks_live"] == 2 + 1
+    assert all(a["kv_blocks_grid"] == 3 * 3 for a in mixed)  # 12 cache tokens / 4
+
+
 @pytest.fixture(scope="module")
 def federation():
     from repro.core.pipeline import CFedRAGConfig, CFedRAGSystem
