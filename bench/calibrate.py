@@ -39,8 +39,8 @@ def main(argv=None) -> int:
     use_compile_cache()
     for seed in (int(s) for s in args.seeds.split(",")):
         out, smp, chunks = serve_window(cell, seed, args.seconds, False, time.monotonic())
-        program = check.compare(cell.model, chunks, smp, seed)
-        control = check.compare(cell.model, chunks, smp, seed, control=True)
+        program = check.compare(cell, chunks, smp, seed)
+        control = check.compare(cell, chunks, smp, seed, control=True)
         print(json.dumps({"seed": seed, "attempted": out["attempted"], "failed": out["failed"],
                           "metrics": out["metrics"], "program": program, "control": control}), flush=True)
     return 0

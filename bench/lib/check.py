@@ -123,15 +123,11 @@ class Reference:
         return T.build_prompt(text, self.tokens[ctx], self.width)
 
 
-def compare(model: dict, chunks: list, smp: Sample, seed: int, control: bool = False) -> dict:
+def compare(cell, chunks: list, smp: Sample, seed: int, control: bool = False) -> dict:
     """The numbers compared, for the program (or, with ``control``, for the
-    control in its place)."""
-    import os
-
-    from bench.lib import spec
-
-    refmod = spec.load_module(os.path.join(spec.BENCH_DIR, "refs", model["reference"] + ".py"),
-                              "bench_ref_" + model["reference"])
+    control in its place): the cell's reference, on weights its
+    architecture's leaf tables remake from the seed."""
+    model = cell.model
     ref = Reference(model, chunks)
     err, gap, missing = ref.retrieval(smp.texts, smp.responses, control)
     # teacher forcing reads the prompts the reference lays out from the
@@ -142,8 +138,8 @@ def compare(model: dict, chunks: list, smp: Sample, seed: int, control: bool = F
         int(got is None or not np.array_equal(np.asarray(got), want))
         for got, want in zip(smp.prompts, prompts)
     )
-    weights = W.make(model, seed, model["torch_dtype"])
-    served, ctl = refmod.logit_gaps(model, weights, prompts, smp.answers, quantize=fp8 if control else None)
+    weights = W.make(cell.arch, model, seed, model["torch_dtype"])
+    served, ctl = cell.reference.logit_gaps(model, weights, prompts, smp.answers, quantize=fp8 if control else None)
     del weights
     return {
         "logit_gap": float((ctl if control else served).max()) if len(served) else float("inf"),

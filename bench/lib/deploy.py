@@ -1,6 +1,8 @@
 """Stand up the system under test from a configuration file: the model on
-the paged engine, four providers, the orchestrator.  This is the only
-module of the benchmark that imports the program.
+the paged engine, four providers, the orchestrator.  Besides the
+architecture modules (``bench/archs``), which bind a model to the
+program's config and parameter tree, this is the only module of the
+benchmark that imports the program.
 
 The configuration file holds the published model config (Hugging Face
 keys) at its top level and the deployment in nested groups: ``serving``
@@ -19,38 +21,6 @@ from bench.lib import corpus as C
 from bench.lib import weights as W
 
 
-def model_config(m: dict):
-    """The program's ``ModelConfig`` for a Qwen3 dense decoder config."""
-    from repro.configs.base import ModelConfig
-
-    s = m["serving"]
-    return ModelConfig(
-        name=m["name"], family="dense",
-        n_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        n_heads=m["num_attention_heads"], n_kv_heads=m["num_key_value_heads"],
-        head_dim=m["head_dim"], d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
-        qk_norm=True, rope_theta=float(m["rope_theta"]), norm_eps=float(m["rms_norm_eps"]),
-        tie_embeddings=bool(m["tie_word_embeddings"]),
-        attn_impl=s["attn_impl"], dtype=m["torch_dtype"], param_dtype=m["torch_dtype"],
-        logit_dtype="float32",
-    )
-
-
-def program_params(w: dict, tied: bool) -> dict:
-    """The benchmark's weights in the program's parameter tree (no copy)."""
-    l = w["layers"]
-    blocks = {"pos0": {
-        "mixer_norm": l["attn_norm"],
-        "attn": {k: l[k] for k in ("wq", "wk", "wv", "wo", "q_norm", "k_norm")},
-        "ffn_norm": l["ffn_norm"],
-        "mlp": {k: l[k] for k in ("wg", "wu", "wd")},
-    }}
-    p = {"embed": {"tok": w["embed"]}, "blocks": blocks, "final_norm": w["final_norm"]}
-    if not tied:
-        p["head"] = {"w": w["head"]}
-    return p
-
-
 @dataclasses.dataclass
 class Deployment:
     system: object  # repro.core.pipeline.CFedRAGSystem
@@ -59,7 +29,8 @@ class Deployment:
     questions: list
 
 
-def build(m: dict, seed: int) -> Deployment:
+def build(cell, seed: int) -> Deployment:
+    """The cell's deployment, its weights made from ``seed``."""
     from repro.core.pipeline import CFedRAGConfig, CFedRAGSystem
     from repro.data.corpus import Chunk, FederatedCorpus
     from repro.data.tokenizer import HashTokenizer
@@ -67,8 +38,9 @@ def build(m: dict, seed: int) -> Deployment:
     from repro.runtime.sharding import ShardingPolicy, base_rules
     from repro.serving.engine import ServeConfig, ServeEngine, engine_generator
 
-    cfg = model_config(m)
-    params = program_params(W.make(m, seed, m["torch_dtype"]), cfg.tie_embeddings)
+    m, arch = cell.model, cell.arch
+    cfg = arch.model_config(m)
+    params = arch.program_params(W.make(arch, m, seed, m["torch_dtype"]), m)
     s, f = m["serving"], m["federation"]
     engine = ServeEngine(
         cfg, ShardingPolicy(rules=base_rules(False), mesh=None), params,

@@ -37,7 +37,7 @@ def step_mfu(run, cell) -> float | None:
     t = run.trace.module_ns(ENGINE_PROGRAMS) / 1e9
     if t <= 0:
         return None
-    flops = run.steps.live().flops(work.Shape.of(cell.model))
+    flops = cell.arch.step_flops(cell.model, run.steps.live(), run)
     return 100.0 * flops / (t * run.extra["peak"]["bf16_flops"])
 
 
@@ -52,12 +52,7 @@ def kernel_roofline(run, cell, program: str, which: str) -> float | None:
     t = run.trace.kernel_ns(program) / 1e9
     if t <= 0:
         return None
-    s = work.Shape.of(cell.model)
-    live = run.steps.live()
-    if which == "prefill":
-        flops, nbytes = s.attn_flops(live.prefill_ctx), s.attn_bytes(live.prefill_kv, live.prefill_q)
-    else:
-        flops, nbytes = s.attn_flops(live.decode_ctx), s.attn_bytes(live.decode_ctx, live.decode_q)
+    flops, nbytes = cell.arch.attn_work(cell.model, run.steps.live(), which, run)
     share, bound = work.roofline_share(flops, nbytes, t, run.extra["peak"])
     run.extra.setdefault("bounds", {})[which] = bound
     return 100.0 * share

@@ -1,10 +1,15 @@
-"""Seeded weights of a dense decoder, made on the device in one jitted call.
+"""Seeded weights, made on the device in one jitted call, from the leaf
+tables of the configuration's architecture module.
 
-Every leaf comes from its own key, ``fold_in(seed key, leaf number)``, and
-a stacked per-layer leaf from ``fold_in(leaf key, layer)``, so the plain
-reference can remake any single layer on its own.  Projections are drawn
-with standard deviation 1/sqrt(fan-in), the embedding with 0.02, and norm
-scales around 1, then cast to the type they are served in.
+The module gives the global leaves, ``{name: (shape, std)}``, and one or
+more groups of layers, ``{group: (n_layers, {name: (shape, std)})}``;
+``std`` is None for a norm scale.  Every leaf comes from its own key: the
+i-th global leaf from ``fold_in(seed key, i)``, and the j-th leaf of the
+layer tables, counted through the groups in order, from
+``fold_in(fold_in(seed key, 16 + j), layer)`` with the layer's place in
+its group, so the plain reference can remake any single layer on its own.
+Leaves are drawn with standard deviation ``std``, norm scales around 1,
+then cast to the type they are served in.
 """
 from __future__ import annotations
 
@@ -14,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-LAYER_LEAVES = ("attn_norm", "wq", "wk", "wv", "wo", "q_norm", "k_norm", "ffn_norm", "wg", "wu", "wd")
+FIRST_LAYER_LEAF = 16  # the leaf number of the first layer leaf; global leaves come before it
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -24,32 +29,17 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.wrap_key_data(jnp.asarray(words), impl="threefry2x32")
 
 
-def dims(m: dict) -> dict:
-    return dict(
-        d=m["hidden_size"], h=m["num_attention_heads"], kv=m["num_key_value_heads"],
-        hd=m["head_dim"], f=m["intermediate_size"], v=m["vocab_size"],
-        n=m["num_hidden_layers"],
+def tables(arch, m: dict) -> tuple:
+    """The architecture's leaf tables for configuration ``m``, as hashable
+    tuples: ``(globals, groups)``."""
+    glob = tuple((name, tuple(shape), std) for name, (shape, std) in arch.global_leaves(m).items())
+    if len(glob) > FIRST_LAYER_LEAF:
+        raise ValueError(f"{len(glob)} global leaves; leaf numbers from {FIRST_LAYER_LEAF} are the layers'")
+    groups = tuple(
+        (group, int(n), tuple((name, tuple(shape), std) for name, (shape, std) in leaves.items()))
+        for group, (n, leaves) in arch.layer_groups(m).items()
     )
-
-
-def layer_shapes(m: dict) -> dict:
-    """Shape and init standard deviation (None: a norm scale) of each
-    per-layer leaf."""
-    z = dims(m)
-    d, h, kv, hd, f = z["d"], z["h"], z["kv"], z["hd"], z["f"]
-    return {
-        "attn_norm": ((d,), None),
-        "wq": ((d, h, hd), d ** -0.5),
-        "wk": ((d, kv, hd), d ** -0.5),
-        "wv": ((d, kv, hd), d ** -0.5),
-        "wo": ((h, hd, d), (h * hd) ** -0.5),
-        "q_norm": ((hd,), None),
-        "k_norm": ((hd,), None),
-        "ffn_norm": ((d,), None),
-        "wg": ((d, f), d ** -0.5),
-        "wu": ((d, f), d ** -0.5),
-        "wd": ((f, d), f ** -0.5),
-    }
+    return glob, groups
 
 
 def _draw(key, shape, std, dtype):
@@ -58,36 +48,26 @@ def _draw(key, shape, std, dtype):
     return x.astype(dtype)
 
 
-def layer(m: dict, key, i, dtype):
-    """Leaves of layer ``i`` (a traced or Python int)."""
-    out = {}
-    for j, (name, (shape, std)) in enumerate(layer_shapes(m).items()):
-        out[name] = _draw(jax.random.fold_in(jax.random.fold_in(key, 16 + j), i), shape, std, dtype)
-    return out
-
-
-def globals_(m: dict, key, dtype):
-    z = dims(m)
-    out = {
-        "embed": _draw(jax.random.fold_in(key, 0), (z["v"], z["d"]), 0.02, dtype),
-        "final_norm": _draw(jax.random.fold_in(key, 1), (z["d"],), None, dtype),
-    }
-    if not m["tie_word_embeddings"]:
-        out["head"] = _draw(jax.random.fold_in(key, 2), (z["d"], z["v"]), z["d"] ** -0.5, dtype)
-    return out
-
-
 @functools.partial(jax.jit, static_argnums=(0, 2))
-def _make(m_items, key, dtype_name):
-    m = dict(m_items)
+def _make(tabs: tuple, key, dtype_name: str) -> dict:
+    """All weights of ``tables(...)``: each global leaf by its name, and
+    each group's leaves stacked on a leading layer axis under the group's
+    name."""
+    glob, groups = tabs
     dtype = jnp.dtype(dtype_name)
-    layers = jax.vmap(lambda i: layer(m, key, i, dtype))(jnp.arange(m["num_hidden_layers"]))
-    return {**globals_(m, key, dtype), "layers": layers}
+    out, j = {}, FIRST_LAYER_LEAF
+    for group, n, leaves in groups:
+        def layer(i, leaves=leaves, j=j):
+            return {name: _draw(jax.random.fold_in(jax.random.fold_in(key, j + k), i), shape, std, dtype)
+                    for k, (name, shape, std) in enumerate(leaves)}
+
+        out[group] = jax.vmap(layer)(jnp.arange(n))
+        j += len(leaves)
+    for i, (name, shape, std) in enumerate(glob):
+        out[name] = _draw(jax.random.fold_in(key, i), shape, std, dtype)
+    return out
 
 
-def make(m: dict, seed: int, dtype: str = "bfloat16") -> dict:
-    """All weights: ``embed``, ``final_norm``, ``head`` when untied, and
-    ``layers`` with every per-layer leaf stacked on a leading layer axis."""
-    keys = ("hidden_size", "num_attention_heads", "num_key_value_heads", "head_dim",
-            "intermediate_size", "vocab_size", "num_hidden_layers", "tie_word_embeddings")
-    return _make(tuple((k, m[k]) for k in keys), seed_key(seed), dtype)
+def make(arch, m: dict, seed: int, dtype: str = "bfloat16") -> dict:
+    """All weights of configuration ``m`` from ``seed``."""
+    return _make(tables(arch, m), seed_key(seed), dtype)

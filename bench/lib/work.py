@@ -1,5 +1,6 @@
-"""Operations and bytes that a dense decoder's serving step needs, counted
-from shapes and from the live geometry of each dispatch.
+"""The live geometry of the engine's dispatches, summed, from which the
+configuration's architecture module (``bench/archs/<model_type>.py``)
+counts operations and bytes; and a roofline share from such counts.
 
 Only live work counts: prompt tokens actually prefilled, tokens actually
 decoded, each with the keys it attends to (its position plus one), and
@@ -14,50 +15,6 @@ from __future__ import annotations
 import dataclasses
 
 import numpy as np
-
-
-@dataclasses.dataclass(frozen=True)
-class Shape:
-    layers: int
-    d: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    kv_bytes: int = 2  # bytes per cached key or value element (bf16 pool)
-    act_bytes: int = 2  # bytes per query element fed to attention (bf16)
-    out_bytes: int = 4  # bytes per attention output element (the kernels write f32)
-
-    @classmethod
-    def of(cls, m: dict) -> "Shape":
-        return cls(m["num_hidden_layers"], m["hidden_size"], m["num_attention_heads"],
-                   m["num_key_value_heads"], m["head_dim"], m["intermediate_size"], m["vocab_size"])
-
-    @property
-    def matmul_flops_per_token(self) -> float:
-        """Projections and MLP of all layers for one token (2 per MAC)."""
-        qkv = self.d * (self.heads + 2 * self.kv_heads) * self.head_dim
-        o = self.heads * self.head_dim * self.d
-        mlp = 3 * self.d * self.d_ff
-        return 2.0 * self.layers * (qkv + o + mlp)
-
-    @property
-    def head_flops(self) -> float:
-        return 2.0 * self.d * self.vocab
-
-    def attn_flops(self, ctx) -> float:
-        """Scores and weighted values, all layers, for tokens attending to
-        ``ctx`` keys each (an array or a number)."""
-        return 4.0 * self.layers * self.heads * self.head_dim * float(np.sum(ctx))
-
-    def attn_bytes(self, kv_len, n_q) -> float:
-        """Least bytes an attention kernel moves, all layers: every key and
-        value of each row's ``kv_len`` read once, its ``n_q`` queries read
-        and outputs written once."""
-        kv = 2.0 * self.kv_heads * self.head_dim * self.kv_bytes * float(np.sum(kv_len))
-        q = self.heads * self.head_dim * (self.act_bytes + self.out_bytes) * float(np.sum(n_q))
-        return self.layers * (kv + q)
 
 
 @dataclasses.dataclass
@@ -75,10 +32,6 @@ class Live:
     def add(self, other: "Live") -> None:
         for f in dataclasses.fields(self):
             setattr(self, f.name, getattr(self, f.name) + getattr(other, f.name))
-
-    def flops(self, s: Shape) -> float:
-        return (self.tokens * s.matmul_flops_per_token + self.head_tokens * s.head_flops
-                + s.attn_flops(self.prefill_ctx) + s.attn_flops(self.decode_ctx))
 
 
 def mixed_live(live: Live, q_start, q_len, is_decode, done, lengths, emitted, row_len) -> None:

@@ -27,7 +27,7 @@ def rehearse(m: dict, one_chip) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from bench.lib import deploy
+    from bench.lib import spec
     from bench.lib import weights as W
     from repro.runtime.sharding import ShardingPolicy, base_rules
     from repro.serving.engine import ServeConfig, ServeEngine
@@ -35,16 +35,15 @@ def rehearse(m: dict, one_chip) -> dict:
     def placed(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
 
-    cfg = deploy.model_config(m)
+    arch = spec.arch(m)
+    cfg = arch.model_config(m)
     s = m["serving"]
-    keys = ("hidden_size", "num_attention_heads", "num_key_value_heads", "head_dim",
-            "intermediate_size", "vocab_size", "num_hidden_layers", "tie_word_embeddings")
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
-    gen = jax.jit(lambda k: W._make.__wrapped__(tuple((n, m[n]) for n in keys),
-                                                jax.random.wrap_key_data(k), m["torch_dtype"]))
+    tabs = W.tables(arch, m)
+    gen = jax.jit(lambda k: W._make.__wrapped__(tabs, jax.random.wrap_key_data(k), m["torch_dtype"]))
     out = {"weights": gen.lower(key).compile()}
     w = placed(jax.eval_shape(gen, key))
-    params = deploy.program_params(w, cfg.tie_embeddings)
+    params = arch.program_params(w, m)
     eng = ServeEngine(cfg, ShardingPolicy(rules=base_rules(False), mesh=None), params, ServeConfig(
         max_batch=s["max_batch"], max_prompt_len=s["max_prompt_len"], max_new_tokens=s["max_new_tokens"],
         paged=True, prefix_cache=s["prefix_cache"], token_budget=s["token_budget"], block_size=s["block_size"],

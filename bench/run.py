@@ -127,7 +127,7 @@ def serve_window(cell, seed: int, seconds: float, traced: bool, t_start: float, 
 
     count_compiles()
     dev = jax.devices()[0]
-    dep = deploy.build(cell.model, seed)
+    dep = deploy.build(cell, seed)
     queries = cell.kind.plan(cell.traffic, dep.questions, seconds)
     cell.kind.warm(dep, cell.traffic, dep.questions)
     if alter is not None:
@@ -203,7 +203,7 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, t_start: float, alte
     from bench.lib import check
 
     out, smp, chunks = serve_window(cell, seed, seconds, traced, t_start, alter)
-    numbers = check.compare(cell.model, chunks, smp, seed, control=control)
+    numbers = check.compare(cell, chunks, smp, seed, control=control)
     ok, shown = check.verdict(numbers, cell.limits)
     log(f"check: {numbers['queries']} queries, {numbers['tokens']} served tokens compared")
     out["correct"] = bool(ok and not out["failed"])

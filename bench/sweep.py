@@ -44,7 +44,7 @@ def main(argv=None) -> int:
         print("sweep: no TPU", file=sys.stderr)
         return 2
     use_compile_cache()
-    dep = deploy.build(cell.model, args.seed)
+    dep = deploy.build(cell, args.seed)
     cell.kind.warm(dep, cell.traffic, dep.questions)
     print(f"set-up {time.monotonic() - T_START:.1f} s", flush=True)
     rates = [float(r) for r in args.rates.split(",")] * args.windows

@@ -27,7 +27,7 @@ def main(argv=None) -> int:
     import jax
     import numpy as np
 
-    from bench.lib import deploy, record, trace
+    from bench.lib import record, spec, trace
     from bench.lib import weights as W
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
@@ -41,8 +41,9 @@ def main(argv=None) -> int:
 
     with open(os.path.join(ROOT, "bench", "configs", "qwen3-0.6b-fed4.json")) as f:
         m = dict(json.load(f), num_hidden_layers=2)
-    cfg = deploy.model_config(m)
-    params = deploy.program_params(W.make(m, 7, "bfloat16"), cfg.tie_embeddings)
+    arch = spec.arch(m)
+    cfg = arch.model_config(m)
+    params = arch.program_params(W.make(arch, m, 7, "bfloat16"), m)
     s = m["serving"]
     eng = ServeEngine(cfg, ShardingPolicy(rules=base_rules(False), mesh=None), params, ServeConfig(
         max_batch=s["max_batch"], max_prompt_len=s["max_prompt_len"], max_new_tokens=s["max_new_tokens"],

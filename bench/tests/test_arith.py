@@ -6,8 +6,10 @@ import math
 import numpy as np
 import pytest
 
-from bench.lib import stats, work
+from bench.lib import spec, stats, work
 from bench.lib.record import Query, Run, StepRecorder
+
+QWEN3 = spec.arch({"model_type": "qwen3"})
 
 QWEN3_06B = {"num_hidden_layers": 28, "hidden_size": 1024, "num_attention_heads": 16,
              "num_key_value_heads": 8, "head_dim": 128, "intermediate_size": 3072,
@@ -37,12 +39,15 @@ def test_query_percentile_reads_unserved_queries_as_the_wait():
 
 
 def test_flops_per_token_of_qwen3_06b_match_a_hand_count():
-    s = work.Shape.of(QWEN3_06B)
+    s = QWEN3.Shape.of(QWEN3_06B)
     per_layer = 1024 * 128 * (16 + 8 + 8) + 16 * 128 * 1024 + 3 * 1024 * 3072  # 15.73M params
     assert s.matmul_flops_per_token == 2 * 28 * per_layer
     assert abs(s.matmul_flops_per_token - 0.881e9) < 0.001e9
     assert s.head_flops == 2 * 1024 * 151936  # 0.311 GFLOP where logits are used
     assert s.attn_flops(1000) == 4 * 28 * 16 * 128 * 1000
+    # one decoded token whose logits are used, attending to 1000 keys
+    one = work.Live(tokens=1, head_tokens=1, decode_ctx=1000.0, decode_q=1)
+    assert QWEN3.step_flops(QWEN3_06B, one) == 2 * 28 * per_layer + 2 * 1024 * 151936 + 4 * 28 * 16 * 128 * 1000
 
 
 class _FakeEngine:
@@ -115,7 +120,7 @@ def _padded_mixed_kernel_work(s, b, w, n_t, bs):
 
 
 def test_roofline_share_of_live_work_cannot_pass_100_percent():
-    s = work.Shape.of(QWEN3_06B)
+    s = QWEN3.Shape.of(QWEN3_06B)
     peak = {"bf16_flops": 197e12, "hbm_bytes_s": 819e9}
     b, w, bs, n_t = 8, 256, 16, 160
     rng = np.random.default_rng(0)
@@ -127,7 +132,7 @@ def test_roofline_share_of_live_work_cannot_pass_100_percent():
         work.mixed_live(live, q_start, q_len, is_dec, rng.random(b) < 0.2,
                         rng.integers(1, 2000, b), rng.integers(1, 400, b), q_start + q_len + rng.integers(0, 2, b))
         f_pad, b_pad = _padded_mixed_kernel_work(s, b, w, n_t, bs)
-        f_live, b_live = s.attn_flops(live.prefill_ctx), s.attn_bytes(live.prefill_kv, live.prefill_q)
+        f_live, b_live = QWEN3.attn_work(QWEN3_06B, live, "prefill")
         assert f_live <= f_pad and b_live <= b_pad
         # a kernel that did its padded work at the chip's peaks
         fastest = max(f_pad / peak["bf16_flops"], b_pad / peak["hbm_bytes_s"])

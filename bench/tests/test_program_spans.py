@@ -27,7 +27,7 @@ def tiny_traced_run(name: str, seed: int):
     program recording over the drive."""
     cell = tiny_cell(name)
     seconds = 3.0 if cell.traffic["kind"] == "open_poisson" else 4.0
-    dep = deploy.build(cell.model, seed)
+    dep = deploy.build(cell, seed)
     queries = cell.kind.plan(cell.traffic, dep.questions, seconds)
     cell.kind.warm(dep, cell.traffic, dep.questions)
     run = record.Run(seconds=seconds, traced=True)

@@ -1,6 +1,6 @@
-"""BENCHMARK.json: every cell finds its configuration, traffic, generator
-kind, metric readers and limits by name, and the file keeps the shape the
-benchmark's contract asks for."""
+"""BENCHMARK.json: every cell finds its configuration, architecture module,
+reference, traffic, generator kind, metric readers and limits by name, and
+the file keeps the shape the benchmark's contract asks for."""
 import json
 import os
 import re
@@ -30,7 +30,41 @@ def test_workload_resolves_by_name(name):
     for k in ("logit_gap", "score_err", "rank_gap", "prompt_diff", "providers_missing"):
         assert k in cell.limits
     ref = os.path.join(spec.BENCH_DIR, "refs", cell.model["reference"] + ".py")
-    assert os.path.exists(ref)
+    assert os.path.exists(ref) and callable(cell.reference.logit_gaps)
+
+
+ARCH_API = ("global_leaves", "layer_groups", "model_config", "program_params", "step_flops", "attn_work")
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
+def test_configuration_resolves_to_an_architecture_module(name):
+    conf = {c["name"]: c for c in BENCH["configs"]}[name]
+    with open(os.path.join(spec.ROOT, conf["file"])) as f:
+        model = json.load(f)
+    arch = spec.arch(model)
+    assert arch.__file__ == os.path.join(spec.BENCH_DIR, "archs", model["model_type"] + ".py")
+    for k in ARCH_API:
+        assert callable(getattr(arch, k)), k
+    assert set(arch.TINY) <= set(model)
+    groups = arch.layer_groups(model)
+    assert groups and all(n > 0 and leaves for n, leaves in groups.values())
+    assert set(arch.global_leaves(model)).isdisjoint(groups)
+
+
+def test_model_type_without_a_module_fails_naming_the_missing_file(tmp_path):
+    """No fallback: a configuration whose ``model_type`` has no module under
+    ``archs/`` stops the cell, and the message names the file."""
+    with open(os.path.join(spec.ROOT, BENCH["configs"][0]["file"])) as f:
+        model = dict(json.load(f), model_type="no_such_arch")
+    (tmp_path / "bench" / "configs").mkdir(parents=True)
+    (tmp_path / "bench" / "configs" / "c.json").write_text(json.dumps(model))
+    bench = dict(BENCH, configs=[dict(BENCH["configs"][0], name="c", file="bench/configs/c.json")],
+                 workloads=[dict(BENCH["workloads"][0], config="c")])
+    missing = os.path.join(str(tmp_path), "bench", "archs", "no_such_arch.py")
+    with pytest.raises(FileNotFoundError, match=re.escape(missing)):
+        spec.cell(bench["workloads"][0]["name"], bench, root=str(tmp_path))
+    with pytest.raises(FileNotFoundError, match=re.escape(os.path.join(spec.BENCH_DIR, "archs", "no_such_arch.py"))):
+        spec.arch(model)
 
 
 def test_benchmark_file_keeps_the_contract_shape():

@@ -1,23 +1,22 @@
-"""A cell of ``BENCHMARK.json`` cut to a size the CPU runs in seconds: two
-layers of width 64, a corpus of 128 short chunks, two slots.  Only the
-sizes change; the harness, the program's path and the check are the
-ones a chip run uses."""
+"""A cell of ``BENCHMARK.json`` cut to a size the CPU runs in seconds: the
+model to its architecture module's ``TINY`` sizes, a corpus of 128 short
+chunks, two slots.  Only the sizes change; the harness, the program's
+path and the check are the ones a chip run uses."""
 from __future__ import annotations
 
 import copy
 
 from bench.lib import spec
 
-MODEL = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
-             intermediate_size=128, num_hidden_layers=2, vocab_size=8192)
 SERVING = dict(max_batch=2, token_budget=64, max_prompt_len=256, max_new_tokens=16)
 CORPUS = dict(n_facts=64, n_distractors=64, chunk_words_median=12, chunk_max_len=32)
 
 
-def tiny_cell(name: str) -> spec.Cell:
-    cell = spec.cell(name)
+def tiny_cell(name: str, root: str = spec.ROOT) -> spec.Cell:
+    """The cell ``name`` of the benchmark at ``root``, cut."""
+    cell = spec.cell(name, root=root)
     cell.model = copy.deepcopy(cell.model)
-    cell.model.update(MODEL)
+    cell.model.update(cell.arch.TINY)
     cell.model["serving"].update(SERVING)
     cell.model["corpus"].update(CORPUS)
     cell.traffic = copy.deepcopy(cell.traffic)
