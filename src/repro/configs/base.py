@@ -42,6 +42,22 @@ class ModelConfig:
     capacity_slack: float = 1.5
     router_aux_weight: float = 0.01
     moe_impl: str = "psum"  # psum (masked-local EP) | a2a (token-resharded EP)
+    # held experts (``moe.held_moe_apply``, DeepSeek-V3 routing: sigmoid
+    # scores, picked by score plus a selection bias, normalised):
+    # ``experts_held`` > 0 makes every MoE layer hold experts
+    # ``[expert_offset, expert_offset + experts_held)`` of the router's
+    # ``n_experts`` (its chip's share under expert parallelism) and compute
+    # only their part, dropless; the router keeps its full width
+    experts_held: int = 0
+    expert_offset: int = 0
+    moe_scale: float = 1.0  # routed_scaling_factor
+    first_dense: int = 0  # first_k_dense_replace: leading dense layers, a group of their own
+
+    # --- latent attention (MLA, kv_lora_rank > 0; absorbed on the paged path) ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- hybrid / ssm mixers ---
     attn_every: int = 1  # attention on layers where i % attn_every == attn_offset
@@ -78,6 +94,22 @@ class ModelConfig:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of the one cached latent head: the latent, then its rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def attn_scale(self) -> float:
+        """Softmax scale: 1/sqrt of the width each query head scores with."""
+        if self.mla:
+            return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        return self.resolved_head_dim ** -0.5
+
+    @property
     def resolved_moe_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
 
@@ -98,6 +130,10 @@ class ModelConfig:
         return "attn"
 
     def ffn_kind(self, i: int) -> str:
+        """FFN of layer ``i`` of the whole stack."""
+        if i < self.first_dense:
+            return "dense"
+        i -= self.first_dense
         if self.n_experts > 0 and (i % self.moe_every) == self.moe_offset:
             return "moe"
         return "dense"
@@ -111,15 +147,22 @@ class ModelConfig:
             p = math.lcm(p, self.attn_every)
         if self.n_experts > 0 and self.moe_every > 1:
             p = math.lcm(p, self.moe_every)
-        assert self.n_layers % p == 0, (self.name, self.n_layers, p)
+        assert (self.n_layers - self.first_dense) % p == 0, (self.name, self.n_layers, p)
         return p
 
     @property
     def n_blocks(self) -> int:
-        return self.n_layers // self.scan_period
+        """Scanned blocks after the leading dense layers."""
+        return (self.n_layers - self.first_dense) // self.scan_period
 
     # --- parameter counting (MODEL_FLOPS denominators) ---------------- #
     def _attn_params(self) -> int:
+        if self.mla:
+            h, r = self.n_heads, self.kv_lora_rank
+            p = self.d_model * h * (self.qk_nope_head_dim + self.qk_rope_head_dim)  # q
+            p += self.d_model * self.latent_dim + r  # kv_a, latent norm
+            p += r * h * (self.qk_nope_head_dim + self.v_head_dim)  # kv_b
+            return p + h * self.v_head_dim * self.d_model  # o
         hd = self.resolved_head_dim
         p = self.d_model * hd * (self.n_heads + 2 * self.n_kv_heads)  # qkv
         p += self.n_heads * hd * self.d_model  # o
@@ -142,7 +185,7 @@ class ModelConfig:
         return 3 * self.d_model * self.d_ff
 
     def _moe_ffn_params(self, active: bool) -> int:
-        e = self.moe_top_k if active else self.n_experts
+        e = self.moe_top_k if active else (self.experts_held or self.n_experts)
         p = 3 * self.d_model * self.resolved_moe_d_ff * e
         p += self.d_model * self.n_experts  # router
         if self.n_shared_experts:

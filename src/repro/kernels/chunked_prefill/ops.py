@@ -10,8 +10,9 @@ from repro.kernels.chunked_prefill.ref import (  # noqa: F401  (partials re-expo
 )
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas",))
-def mixed_prefill_attention(q, k_pool, v_pool, block_tables, desc, use_pallas: bool = False):
+@functools.partial(jax.jit, static_argnames=("use_pallas", "scale", "v_dim"))
+def mixed_prefill_attention(q, k_pool, v_pool, block_tables, desc, use_pallas: bool = False,
+                            scale: float | None = None, v_dim: int | None = None):
     """Ragged mixed prefill/decode attention through a block table over a
     shared KV pool.  ``use_pallas=True`` streams pool blocks via
     scalar-prefetch index maps (interpreted on the CPU); the
@@ -31,7 +32,11 @@ def mixed_prefill_attention(q, k_pool, v_pool, block_tables, desc, use_pallas: b
         lanes 1..k the drafter's proposals, and lane ``j``'s output
         equals a plain decode after emitting lanes ``< j``, which is
         what makes greedy accept-prefix bit-identical to 1-token decode.
+
+    ``v_pool`` None: the values are K's first ``v_dim`` lanes (a latent
+    head); ``scale`` None is 1/sqrt(dh).
     """
     if use_pallas:
-        return mixed_prefill_attention_pallas(q, k_pool, v_pool, block_tables, desc)
-    return mixed_prefill_attention_ref(q, k_pool, v_pool, block_tables, desc)
+        return mixed_prefill_attention_pallas(q, k_pool, v_pool, block_tables, desc,
+                                              scale=scale, v_dim=v_dim)
+    return mixed_prefill_attention_ref(q, k_pool, v_pool, block_tables, desc, scale, v_dim)

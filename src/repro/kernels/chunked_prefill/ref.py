@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def mixed_prefill_attention_ref(q, k_pool, v_pool, block_tables, desc):
+def mixed_prefill_attention_ref(q, k_pool, v_pool, block_tables, desc, scale=None, v_dim=None):
     """Oracle: gather each row's contiguous pool view, dense masked softmax.
 
     q:            (R, W, H, dh) — W ragged query lanes per row
@@ -32,15 +32,22 @@ def mixed_prefill_attention_ref(q, k_pool, v_pool, block_tables, desc):
     would give uniform probs over all-(-1e30) logits, so probs are zeroed
     wherever the mask is false (an exact identity for live lanes: masked
     positions already carry exp(-1e30 - m) == +0.0).
+
+    ``v_pool`` None: the values are the first ``v_dim`` lanes of K (a
+    latent head); ``scale`` None is 1/sqrt(dh).
     """
     r, w, h, dh = q.shape
     bs, kv = k_pool.shape[1], k_pool.shape[2]
     tbl = block_tables[desc[:, 0]]  # (R, n_t)
     s_pad = tbl.shape[1] * bs
     k_view = k_pool[tbl].reshape(r, s_pad, kv, dh).astype(jnp.float32)
-    v_view = v_pool[tbl].reshape(r, s_pad, kv, dh).astype(jnp.float32)
+    if v_pool is None:
+        v_view = k_view[..., :v_dim]
+    else:
+        v_view = v_pool[tbl].reshape(r, s_pad, kv, dh).astype(jnp.float32)
     qr = q.astype(jnp.float32).reshape(r, w, kv, h // kv, dh)
-    logits = jnp.einsum("rwkgd,rskd->rkgws", qr, k_view) / np.sqrt(dh)
+    logits = jnp.einsum("rwkgd,rskd->rkgws", qr, k_view)
+    logits = logits / np.sqrt(dh) if scale is None else logits * scale
     lane = jnp.arange(w)
     kpos = jnp.arange(s_pad)
     qpos = desc[:, 1][:, None] + lane[None, :]  # (R, W)
@@ -53,7 +60,7 @@ def mixed_prefill_attention_ref(q, k_pool, v_pool, block_tables, desc):
     p = jax.nn.softmax(logits, axis=-1)
     p = jnp.where(valid[:, None, None], p, 0.0)
     out = jnp.einsum("rkgws,rskd->rwkgd", p, v_view)
-    return out.reshape(r, w, h, dh).astype(q.dtype)
+    return out.reshape(r, w, h, v_view.shape[-1]).astype(q.dtype)
 
 
 def mixed_prefill_partials(q, k_pool, v_pool, block_tables, desc, owned=None):

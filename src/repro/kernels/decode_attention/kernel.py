@@ -118,14 +118,21 @@ def decode_attention_pallas(
     return out.reshape(b, h, dh).astype(q.dtype)
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                  m_scr, l_scr, acc_scr, *, bs, scale, n_t):
+def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, *refs, bs, scale, n_t, dv):
     """Grid (B, KV, n_max_blocks).  The scalar-prefetched block table
     drives the K/V BlockSpec index maps, so pool block ``tbl[b, t]``
     streams into VMEM for (batch b, logical block t) — the gather never
     materializes in HBM.  Masking is positional: logical position
     ``t * bs + lane`` is valid iff < lengths[b] — trash-backed lanes are
-    always past the length and contribute exp(-inf) = 0 exactly."""
+    always past the length and contribute exp(-inf) = 0 exactly.
+
+    ``dv``: None when V has a pool of its own (``refs`` starts with its
+    block); else V is the first ``dv`` lanes of the K block, read once
+    (the latent head of absorbed MLA)."""
+    if dv is None:
+        v_ref, o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
+    else:
+        o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
     tj = pl.program_id(2)
     bi = pl.program_id(0)
 
@@ -137,7 +144,7 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
     q = q_ref[0, 0].astype(jnp.float32)  # (G, dh)
     k = k_ref[0, 0].astype(jnp.float32)  # (BS, dh)
-    v = v_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32) if dv is None else k[:, :dv]
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # (G, BS)
@@ -164,24 +171,39 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 def paged_decode_attention_pallas(
     q: jax.Array,  # (B, H, dh) — one new token per sequence
     k_pool: jax.Array,  # (n_pool, bs, KV, dh) shared block pool
-    v_pool: jax.Array,
+    v_pool: jax.Array | None,  # (n_pool, bs, KV, dh), or None: V is K's first v_dim lanes
     block_tables: jax.Array,  # (B, n_max_blocks) int32 pool ids per row
     lengths: jax.Array,  # (B,) valid cache length per sequence
+    *,
+    scale: float | None = None,  # None -> 1/sqrt(dh)
+    v_dim: int | None = None,  # with v_pool None: V's lanes of the K head
 ):
     """Flash-decode over a PAGED KV cache: same online-softmax stream as
     ``decode_attention_pallas``, but the sequence axis is a block table —
     the BlockSpec index map reads the scalar-prefetched table to pick
     which pool block to DMA per grid step (the vLLM-style paged-attention
-    gather, done by the memory system instead of an HBM materialize)."""
+    gather, done by the memory system instead of an HBM materialize).
+
+    With ``v_pool`` None (absorbed latent attention: one KV head whose
+    first ``v_dim`` lanes are the values) each grid step reads the block
+    once for both, and the output has ``v_dim`` lanes."""
     b, h, dh = q.shape
     n_pool, bs, kv = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     n_t = block_tables.shape[1]
     g = h // kv
-    scale = 1.0 / np.sqrt(dh)
+    scale = 1.0 / np.sqrt(dh) if scale is None else scale
+    shared = v_pool is None
+    dv = v_dim if shared else dh
 
     qg = q.reshape(b, kv, g, dh)
-    kt = k_pool.transpose(0, 2, 1, 3)  # (n_pool, KV, BS, dh)
-    vt = v_pool.transpose(0, 2, 1, 3)
+    if kv == 1:  # moving a unit axis: a reshape, no copy of the pool
+        kt = k_pool.reshape(n_pool, 1, bs, dh)
+    else:
+        kt = k_pool.transpose(0, 2, 1, 3)  # (n_pool, KV, BS, dh)
+    kv_spec = pl.BlockSpec((1, 1, bs, dh), lambda bi, ki, tj, tbl, lens: (tbl[bi, tj], ki, 0, 0))
+    operands = [qg, kt]
+    if not shared:
+        operands.append(v_pool.transpose(0, 2, 1, 3))
     grid = (b, kv, n_t)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -189,26 +211,25 @@ def paged_decode_attention_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, g, dh), lambda bi, ki, tj, tbl, lens: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, 1, bs, dh), lambda bi, ki, tj, tbl, lens: (tbl[bi, tj], ki, 0, 0)),
-            pl.BlockSpec((1, 1, bs, dh), lambda bi, ki, tj, tbl, lens: (tbl[bi, tj], ki, 0, 0)),
-        ],
+        ] + [kv_spec] * (len(operands) - 1),
         out_specs=[
-            pl.BlockSpec((1, 1, g, dh), lambda bi, ki, tj, tbl, lens: (bi, ki, 0, 0)),
+            pl.BlockSpec((1, 1, g, dv), lambda bi, ki, tj, tbl, lens: (bi, ki, 0, 0)),
             pl.BlockSpec((1, 1, g, 1), lambda bi, ki, tj, tbl, lens: (bi, ki, 0, 0)),
             pl.BlockSpec((1, 1, g, 1), lambda bi, ki, tj, tbl, lens: (bi, ki, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dh), jnp.float32),
+            pltpu.VMEM((g, dv), jnp.float32),
         ],
     )
     def build(interpret):
         return pl.pallas_call(
-            functools.partial(_paged_kernel, bs=bs, scale=scale, n_t=n_t),
+            functools.partial(_paged_kernel, bs=bs, scale=scale, n_t=n_t,
+                              dv=dv if shared else None),
             grid_spec=grid_spec,
             out_shape=[
-                jax.ShapeDtypeStruct((b, kv, g, dh), jnp.float32),
+                jax.ShapeDtypeStruct((b, kv, g, dv), jnp.float32),
                 jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32),
                 jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32),
             ],
@@ -216,10 +237,10 @@ def paged_decode_attention_pallas(
         )
 
     o, m, l = on_backend(build)(
-        block_tables.astype(jnp.int32), lengths.astype(jnp.int32), qg, kt, vt
+        block_tables.astype(jnp.int32), lengths.astype(jnp.int32), *operands
     )
     out = o / jnp.maximum(l, 1e-30)
-    return out.reshape(b, h, dh).astype(q.dtype)
+    return out.reshape(b, h, dv).astype(q.dtype)
 
 
 def combine_partials(o, m, l):

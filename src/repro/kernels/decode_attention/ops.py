@@ -21,11 +21,15 @@ def decode_attention(q, k_cache, v_cache, lengths, use_pallas: bool = False):
     return decode_attention_ref(q, k_cache, v_cache, lengths)
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas",))
-def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, use_pallas: bool = False):
+@functools.partial(jax.jit, static_argnames=("use_pallas", "scale", "v_dim"))
+def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, use_pallas: bool = False,
+                           scale: float | None = None, v_dim: int | None = None):
     """Single-token attention through a block table over a shared KV pool.
     ``use_pallas=True`` streams pool blocks via scalar-prefetch index maps
-    (interpreted on the CPU); the default gathers in XLA."""
+    (interpreted on the CPU); the default gathers in XLA.  ``v_pool`` None:
+    the values are K's first ``v_dim`` lanes (a latent head); ``scale``
+    None is 1/sqrt(dh)."""
     if use_pallas:
-        return paged_decode_attention_pallas(q, k_pool, v_pool, block_tables, lengths)
-    return paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths)
+        return paged_decode_attention_pallas(q, k_pool, v_pool, block_tables, lengths,
+                                             scale=scale, v_dim=v_dim)
+    return paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths, scale, v_dim)

@@ -183,6 +183,71 @@ def attn_apply(cfg: ModelConfig, pol: ShardingPolicy, p, x, positions, *, causal
     return pol.shard(out, "act_batch", "act_seq", "act_embed")
 
 
+# --------------------------------------------------------------------- #
+# latent attention (MLA), absorbed: the paged serving path
+# --------------------------------------------------------------------- #
+
+
+def mla_specs(cfg: ModelConfig) -> dict:
+    """DeepSeek-V3 attention without a query LoRA: ``wq`` d -> heads x
+    (nope + rope); ``wkv_a`` d -> latent + one shared rope key; ``kv_norm``
+    the RMSNorm of the latent; ``wkv_b`` latent -> heads x (k_nope + v);
+    ``wo`` heads x v -> d."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {
+        "wq": ParamSpec((d, h, nope + rope), ("embed", "heads", "head_dim"), "fan_in", fan_in_dims=(0,)),
+        "wkv_a": ParamSpec((d, r + rope), ("embed", None), "fan_in", fan_in_dims=(0,)),
+        "kv_norm": ParamSpec((r,), ("norm",), "ones"),
+        "wkv_b": ParamSpec((r, h, nope + dv), (None, "heads", "head_dim"), "fan_in", fan_in_dims=(0,)),
+        "wo": ParamSpec((h, dv, d), ("heads", "head_dim", "embed"), "fan_in", fan_in_dims=(0, 1)),
+    }
+
+
+def mla_qkv(cfg: ModelConfig, pol: ShardingPolicy, p, x, positions):
+    """Absorbed MLA projections.  x: (B,S,d) -> ``q`` (B,S,H,r+rope), each
+    head's nope query folded through its key up-projection W_uk into the
+    latent's space beside its roped part; ``latent`` (B,S,1,r+rope), the
+    normed latent beside the roped shared key — the one cached head, whose
+    first ``r`` lanes are also the values."""
+    dt = x.dtype
+    nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
+    q = pol.shard(q, "act_batch", "act_seq", "act_heads", None)
+    kv = x @ p["wkv_a"].astype(dt)  # (B,S,r+rope)
+    c = rmsnorm(kv[..., :r], p["kv_norm"], cfg.norm_eps)
+    k_rope = apply_rope(kv[..., None, r:], positions, cfg.rope_theta)
+    q_rope = apply_rope(q[..., nope:], positions, cfg.rope_theta)
+    q_lat = jnp.einsum("bshk,chk->bshc", q[..., :nope], p["wkv_b"][..., :nope].astype(dt))
+    return jnp.concatenate([q_lat, q_rope], -1), jnp.concatenate([c[..., None, :], k_rope], -1)
+
+
+def mla_out(cfg: ModelConfig, pol: ShardingPolicy, p, o):
+    """Attention over the latent values (B,S,H,r) -> W_uv per head, then
+    the output projection -> (B,S,d)."""
+    dt = o.dtype
+    v = jnp.einsum("bshc,chk->bshk", o, p["wkv_b"][..., cfg.qk_nope_head_dim:].astype(dt))
+    out = jnp.einsum("bshk,hkd->bsd", v, p["wo"].astype(dt))
+    return pol.shard(out, "act_batch", "act_seq", "act_embed")
+
+
+def _project_paged(cfg, pol, p, x, positions):
+    """The paged path's projections: ``(q, k_new, v_new)``; for latent
+    attention ``k_new`` is the latent head and ``v_new`` None (the values
+    are its first ``kv_lora_rank`` lanes)."""
+    if cfg.mla:
+        q, latent = mla_qkv(cfg, pol, p, x, positions)
+        return q, latent, None
+    return attn_qkv(cfg, pol, p, x, positions)
+
+
+def _attn_out_paged(cfg, pol, p, o, dt):
+    if cfg.mla:
+        return mla_out(cfg, pol, p, o)
+    out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(dt))
+    return pol.shard(out, "act_batch", "act_seq", "act_embed")
+
+
 def attn_decode(cfg: ModelConfig, pol: ShardingPolicy, p, x, k_cache, v_cache, pos):
     """Single-token decode.  x: (B,1,d); caches: (B,S,KV,hd); pos: scalar
     write position, or (B,) per-row positions for ragged batches (each row
@@ -307,7 +372,7 @@ def attn_decode_paged(cfg: ModelConfig, pol: ShardingPolicy, p, x, k_pool, v_poo
     """
     b = x.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
-    q, k_new, v_new = attn_qkv(cfg, pol, p, x, pos[:, None])
+    q, k_new, v_new = _project_paged(cfg, pol, p, x, pos[:, None])
     if k_pool.ndim == 5:
         # sharded pool (n_shards, n_local+1, bs, KV, hd): decode is the
         # W=1 case of the distributed mixed dispatch.  A free slot's
@@ -325,25 +390,30 @@ def attn_decode_paged(cfg: ModelConfig, pol: ShardingPolicy, p, x, k_pool, v_poo
     # rows own disjoint blocks (the pool allocator guarantees it), so the
     # (bid, off) scatter targets are distinct across live rows
     k_pool = k_pool.at[bid, off].set(k_new[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[bid, off].set(v_new[:, 0].astype(v_pool.dtype))
+    if v_pool is not None:
+        v_pool = v_pool.at[bid, off].set(v_new[:, 0].astype(v_pool.dtype))
+    # latent attention: one head, the values its first kv_lora_rank lanes
+    scale, v_dim = (cfg.attn_scale, cfg.kv_lora_rank) if cfg.mla else (None, None)
     if cfg.attn_impl == "pallas":
         from repro.kernels.decode_attention import ops as da_ops
 
         out = da_ops.paged_decode_attention(
-            q[:, 0], k_pool, v_pool, block_tables, pos + 1, use_pallas=True
+            q[:, 0], k_pool, v_pool, block_tables, pos + 1, use_pallas=True,
+            scale=scale, v_dim=v_dim,
         )[:, None]  # (B,1,H,hd)
     else:
         s_pad = block_tables.shape[1] * block_size
         k_view = k_pool[block_tables].reshape(b, s_pad, *k_pool.shape[2:])
-        v_view = v_pool[block_tables].reshape(b, s_pad, *v_pool.shape[2:])
-        scale = 1.0 / np.sqrt(q.shape[-1])
+        v_view = (k_view[..., :v_dim] if v_pool is None
+                  else v_pool[block_tables].reshape(b, s_pad, *v_pool.shape[2:]))
+        scale = 1.0 / np.sqrt(q.shape[-1]) if scale is None else scale
         logits = _gqa_logits(q, k_view.astype(q.dtype)) * scale  # (B,KV,G,1,S_pad)
         kpos = jnp.arange(s_pad)
         valid = (kpos[None, :] <= pos[:, None]).reshape(b, 1, 1, 1, s_pad)
         logits = jnp.where(valid, logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1)
         out = _gqa_out(probs, v_view.astype(q.dtype), q.dtype)  # (B,1,H,hd)
-    out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
+    out = _attn_out_paged(cfg, pol, p, out, x.dtype)
     return out, k_pool, v_pool
 
 
@@ -376,10 +446,15 @@ def attn_mixed_paged(cfg: ModelConfig, pol: ShardingPolicy, p, x, k_pool, v_pool
         kernel — descriptors + block table ride scalar prefetch, pool
         blocks stream straight into VMEM (interpreted on the CPU).
 
+    Latent attention (``cfg.mla``) takes its one latent head as
+    ``k_pool`` and ``v_pool`` None: the same scatter and kernel call, with
+    the values the latent's first ``kv_lora_rank`` lanes and the
+    published softmax scale.
+
     Returns ``(o, k_pool, v_pool)`` with the fresh K/V already resident.
     """
     b, w = x.shape[0], x.shape[1]
-    q, k_new, v_new = attn_qkv(cfg, pol, p, x, positions)
+    q, k_new, v_new = _project_paged(cfg, pol, p, x, positions)
     if k_pool.ndim == 5:
         # sharded pool: distributed dispatch — per-shard scatter +
         # chunked-prefill partials, merged by dist_decode's combine
@@ -404,9 +479,12 @@ def attn_mixed_paged(cfg: ModelConfig, pol: ShardingPolicy, p, x, k_pool, v_pool
     # live lanes hit disjoint (bid, off) slots across rows (the allocator
     # guarantees block ownership); dead-lane collisions land in trash
     k_pool = k_pool.at[bid, off].set(k_new.astype(k_pool.dtype))
-    v_pool = v_pool.at[bid, off].set(v_new.astype(v_pool.dtype))
+    if v_pool is not None:
+        v_pool = v_pool.at[bid, off].set(v_new.astype(v_pool.dtype))
     q_start = positions[:, 0]
     kv_len = q_start + q_len
+    # latent attention: one head, the values its first kv_lora_rank lanes
+    scale, v_dim = (cfg.attn_scale, cfg.kv_lora_rank) if cfg.mla else (None, None)
     if cfg.attn_impl == "pallas":
         from repro.kernels.chunked_prefill import ops as cp_ops
 
@@ -414,12 +492,13 @@ def attn_mixed_paged(cfg: ModelConfig, pol: ShardingPolicy, p, x, k_pool, v_pool
             [jnp.arange(b), q_start, q_len, kv_len], axis=1
         ).astype(jnp.int32)
         out = cp_ops.mixed_prefill_attention(
-            q, k_pool, v_pool, block_tables, desc, use_pallas=True
+            q, k_pool, v_pool, block_tables, desc, use_pallas=True, scale=scale, v_dim=v_dim,
         )  # (B,W,H,hd)
     else:
         k_view = k_pool[block_tables].reshape(b, s_pad, *k_pool.shape[2:])
-        v_view = v_pool[block_tables].reshape(b, s_pad, *v_pool.shape[2:])
-        scale = 1.0 / np.sqrt(q.shape[-1])
+        v_view = (k_view[..., :v_dim] if v_pool is None
+                  else v_pool[block_tables].reshape(b, s_pad, *v_pool.shape[2:]))
+        scale = 1.0 / np.sqrt(q.shape[-1]) if scale is None else scale
         logits = _gqa_logits(q, k_view.astype(q.dtype)) * scale  # (B,KV,G,W,S_pad)
         kpos = jnp.arange(s_pad)
         valid = (
@@ -432,8 +511,7 @@ def attn_mixed_paged(cfg: ModelConfig, pol: ShardingPolicy, p, x, k_pool, v_pool
         probs = jnp.where(valid[:, None, None], probs, 0.0)
         out = _gqa_out(probs, v_view.astype(q.dtype), q.dtype)  # (B,W,H,hd)
     out = pol.shard(out, "act_batch", "act_seq", "act_heads", None)
-    out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
-    out = pol.shard(out, "act_batch", "act_seq", "act_embed")
+    out = _attn_out_paged(cfg, pol, p, out, x.dtype)
     return out, k_pool, v_pool
 
 

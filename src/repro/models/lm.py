@@ -34,16 +34,16 @@ f32 = jnp.float32
 # --------------------------------------------------------------------- #
 
 
-def _position_specs(cfg: ModelConfig, i: int) -> dict:
+def _position_specs(cfg: ModelConfig, i: int, ffn: str) -> dict:
     d = cfg.d_model
     s: dict[str, Any] = {"mixer_norm": ParamSpec((d,), ("norm",), "ones")}
     if cfg.mixer_kind(i) == "attn":
-        s["attn"] = L.attn_specs(cfg)
+        s["attn"] = L.mla_specs(cfg) if cfg.mla else L.attn_specs(cfg)
     else:
         s["mamba"] = M.mamba_specs(cfg)
-    if cfg.ffn_kind(i) == "moe":
+    if ffn == "moe":
         s["ffn_norm"] = ParamSpec((d,), ("norm",), "ones")
-        s["moe"] = MOE.moe_specs(cfg)
+        s["moe"] = MOE.held_moe_specs(cfg) if cfg.experts_held else MOE.moe_specs(cfg)
     elif cfg.d_ff > 0:
         s["ffn_norm"] = ParamSpec((d,), ("norm",), "ones")
         s["mlp"] = L.mlp_specs(cfg)
@@ -61,14 +61,20 @@ def _stack_specs(tree, n: int, axis: str = "layers"):
     )
 
 
+def _groups(cfg: ModelConfig) -> dict:
+    """Layer groups, each ``(layers, FFN kind of each scanned position)``:
+    ``lead``, the leading dense layers, when the config has them; then
+    ``blocks``, ``n_blocks`` repeats of ``scan_period`` positions."""
+    groups = {"lead": (cfg.first_dense, ["dense"])} if cfg.first_dense else {}
+    groups["blocks"] = (cfg.n_blocks, [cfg.ffn_kind(cfg.first_dense + j) for j in range(cfg.scan_period)])
+    return groups
+
+
 def param_specs(cfg: ModelConfig) -> dict:
-    period = cfg.scan_period
-    block = {f"pos{j}": _position_specs(cfg, j) for j in range(period)}
-    specs = {
-        "embed": L.embed_specs(cfg),
-        "blocks": _stack_specs(block, cfg.n_blocks),
-        "final_norm": ParamSpec((cfg.d_model,), ("norm",), "ones"),
-    }
+    specs = {"embed": L.embed_specs(cfg), "final_norm": ParamSpec((cfg.d_model,), ("norm",), "ones")}
+    for group, (n, ffns) in _groups(cfg).items():
+        block = {f"pos{j}": _position_specs(cfg, j, f) for j, f in enumerate(ffns)}
+        specs[group] = _stack_specs(block, n)
     specs.update({"head": h} if (h := L.head_specs(cfg)) else {})
     return specs
 
@@ -78,19 +84,42 @@ def param_specs(cfg: ModelConfig) -> dict:
 # --------------------------------------------------------------------- #
 
 
-def _run_position(cfg, pol, i, pp, h, positions, mode, cache_in, pos, paged=None):
+def _run_position(cfg, pol, i, pp, h, positions, mode, cache_in, pos, paged=None, ffn=None,
+                  live=None, rows=None):
     """One layer (mixer + ffn).  cache_in: per-position cache pytree or None.
+    ``ffn``: the layer's FFN kind (default ``cfg.ffn_kind(i)``); ``live``:
+    the tokens whose output is used, gathered at ``rows``
+    (``moe.live_rows``), for a held-expert layer.
     ``paged``: None (contiguous cache) or ``(block_tables, block_size)``
     (+ ``q_len`` in ``mixed`` mode) — attention then reads/writes K/V
     through the block table (non-attention state is per-slot in both
     layouts).  ``mixed`` is the unified serving mode: each row carries a
     prompt chunk or a single decode token, and the layer scatters fresh
     K/V into the pool before attending, so prompts may resume at any
-    chunk boundary.  Returns (h, cache_out, aux)."""
+    chunk boundary.  Latent attention (``cfg.mla``) keeps one pool leaf,
+    ``latent``, and runs on the paged modes only.  Returns (h, cache_out,
+    aux, routing counters): int32[3] from a held-expert layer, else None."""
     aux = jnp.zeros((), f32)
+    stats = None
+    ffn = cfg.ffn_kind(i) if ffn is None else ffn
     x = L.rmsnorm(h, pp["mixer_norm"], cfg.norm_eps)
     cache_out = None
-    if cfg.mixer_kind(i) == "attn":
+    if cfg.mla:
+        if mode not in ("mixed", "decode") or paged is None:
+            raise NotImplementedError(
+                "latent attention runs on the paged serving path only (mixed and "
+                f"paged decode steps), not in {mode!r} mode")
+        if mode == "decode":
+            tables, bs, mesh = paged
+            o, lat, _ = L.attn_decode_paged(
+                cfg, pol, pp["attn"], x, cache_in["latent"], None, pos, tables, bs, mesh=mesh)
+        else:
+            tables, bs, q_len, mesh = paged
+            o, lat, _ = L.attn_mixed_paged(
+                cfg, pol, pp["attn"], x, cache_in["latent"], None,
+                positions, tables, bs, q_len, mesh=mesh)
+        cache_out = {"latent": lat}
+    elif cfg.mixer_kind(i) == "attn":
         if mode == "decode" and paged is not None:
             tables, bs, mesh = paged
             o, k_c, v_c = L.attn_decode_paged(
@@ -139,46 +168,64 @@ def _run_position(cfg, pol, i, pp, h, positions, mode, cache_in, pos, paged=None
                 cache_out = {"conv": conv, "ssm": ssm}
     h = h + o
     if "ffn_norm" not in pp:  # pure-SSM blocks (mamba2) have no FFN
-        return h, cache_out, aux
+        return h, cache_out, aux, stats
     x = L.rmsnorm(h, pp["ffn_norm"], cfg.norm_eps)
-    if cfg.ffn_kind(i) == "moe":
+    if ffn == "moe" and cfg.experts_held:
+        o, stats = MOE.held_moe_apply(cfg, pol, pp["moe"], x, live, rows)
+    elif ffn == "moe":
         o, aux = MOE.moe_apply(cfg, pol, pp["moe"], x)
     else:
         o = L.mlp_apply(cfg, pol, pp["mlp"], x)
-    return h + o, cache_out, aux
+    return h + o, cache_out, aux, stats
 
 
-def _run_blocks(cfg, pol, params, h, positions, mode="train", cache=None, pos=0, paged=None):
-    """Scan over blocks.  cache: stacked pytree (n_blocks leading) or None.
-    ``paged``: see ``_run_position`` (the block table is shared across
-    layers, so it rides in as a closure constant, not a scanned leaf).
-    Returns (h, new_cache, aux_total)."""
-    period = cfg.scan_period
+def _run_blocks(cfg, pol, params, h, positions, mode="train", cache=None, pos=0, paged=None,
+                live=None, rows=None):
+    """Scan over blocks, group by group (``_groups``: the leading dense
+    layers, then the periodic stack).  cache: per group, a stacked pytree
+    (layers leading) or None.  ``paged``: see ``_run_position`` (the block
+    table is shared across layers, so it rides in as a closure constant,
+    not a scanned leaf).  Returns (h, new_cache, aux_total, routing
+    counters int32[3] summed over the held-expert layers, or None)."""
+    aux_tot = jnp.zeros((), f32)
+    # routing counters ride the carry only where a layer holds experts
+    stats_tot = jnp.zeros((len(MOE.ROUTING_COUNTERS),), jnp.int32) if cfg.experts_held else None
+    new_cache = {}
+    for group, (n, ffns) in _groups(cfg).items():
 
-    def body(carry, xs):
-        hh, aux_tot = carry
-        bp, cache_blk = xs
-        new_cache = {}
-        for j in range(period):
-            c_in = cache_blk.get(f"pos{j}") if cache_blk else None
-            hh, c_out, aux = _run_position(
-                cfg, pol, j, bp[f"pos{j}"], hh, positions, mode, c_in, pos, paged
-            )
-            if c_out is not None:
-                new_cache[f"pos{j}"] = c_out
-            aux_tot = aux_tot + aux
-        return (hh, aux_tot), (new_cache or None)
+        def body(carry, xs, ffns=ffns):
+            hh, aux_tot, stats_tot = carry
+            bp, cache_blk = xs
+            out = {}
+            for j, ffn in enumerate(ffns):
+                c_in = cache_blk.get(f"pos{j}") if cache_blk else None
+                hh, c_out, aux, stats = _run_position(
+                    cfg, pol, j, bp[f"pos{j}"], hh, positions, mode, c_in, pos, paged,
+                    ffn=ffn, live=live, rows=rows,
+                )
+                if c_out is not None:
+                    out[f"pos{j}"] = c_out
+                aux_tot = aux_tot + aux
+                if stats is not None:
+                    stats_tot = stats_tot + stats
+            return (hh, aux_tot, stats_tot), (out or None)
 
-    if cfg.remat == "block" and mode == "train":
-        body = jax.checkpoint(body)
-    (h, aux), new_cache = jax.lax.scan(
-        body,
-        (h, jnp.zeros((), f32)),
-        (params["blocks"], cache),
-        unroll=cfg.n_blocks if cfg.scan_unroll else 1,
-    )
+        if cfg.remat == "block" and mode == "train":
+            body = jax.checkpoint(body)
+        if cache is None:
+            group_cache = None
+        elif group == "lead":
+            group_cache = cache["lead"]
+        else:  # the periodic stack keeps the top level, as before groups
+            group_cache = {k: v for k, v in cache.items() if k != "lead"}
+        (h, aux_tot, stats_tot), c = jax.lax.scan(
+            body, (h, aux_tot, stats_tot), (params[group], group_cache),
+            unroll=n if cfg.scan_unroll else 1,
+        )
+        if c is not None:
+            new_cache.update(c if group == "blocks" else {group: c})
     n_moe = sum(cfg.ffn_kind(i) == "moe" for i in range(cfg.n_layers))
-    return h, new_cache, aux / max(n_moe, 1)
+    return h, (new_cache or None), aux_tot / max(n_moe, 1), stats_tot
 
 
 def _embed_inputs(cfg, pol, params, batch):
@@ -199,7 +246,7 @@ def forward(cfg: ModelConfig, pol: ShardingPolicy, params, batch):
     tokens = batch["tokens"]
     h = _embed_inputs(cfg, pol, params, batch)
     positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
-    h, _, aux = _run_blocks(cfg, pol, params, h, positions, mode="train")
+    h, _, aux, _ = _run_blocks(cfg, pol, params, h, positions, mode="train")
     h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps)
     return L.head_apply(cfg, pol, params, h), aux
 
@@ -230,6 +277,7 @@ def loss_fn(cfg: ModelConfig, pol: ShardingPolicy, params, batch):
 
 def init_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype=jnp.bfloat16, abstract=False):
     """Stacked decode cache (n_blocks leading axis)."""
+    _contiguous_only(cfg)
     kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     h, hdm, g, ds, w = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state, cfg.conv_width
 
@@ -263,7 +311,11 @@ def init_paged_cache(cfg: ModelConfig, n_pool_blocks: int, block_size: int, n_sl
     per-request block tables; SSM/conv state has no sequence axis to page,
     so those leaves keep the per-slot ``(n_layer_blocks, n_slots, ...)``
     layout of ``init_cache``.  The caller reserves one pool index as the
-    trash block that unallocated table entries point at.
+    trash block that unallocated table entries point at.  Latent attention
+    (``cfg.mla``) keeps one leaf, ``latent`` ``(..., block_size, 1,
+    kv_lora_rank + qk_rope_head_dim)``: the one cached head, whose first
+    lanes are also the values.  A config with leading dense layers keeps
+    their leaves under ``lead``, beside the periodic stack's.
 
     ``n_shards``: sharded serving layout — pool leaves gain a leading
     shard axis ``(n_layer_blocks, n_shards, n_pool_blocks, block_size,
@@ -272,38 +324,38 @@ def init_paged_cache(cfg: ModelConfig, n_pool_blocks: int, block_size: int, n_sl
     ``P(None, "data", ...)``)."""
     kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     h, hdm, g, ds, w = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state, cfg.conv_width
+    shard = () if n_shards is None else (n_shards,)
 
-    def mk(shape, dt):
-        return jnp.zeros((cfg.n_blocks,) + shape, dt)
+    def position(n, j):
+        def mk(shape, dt):
+            return jnp.zeros((n,) + shape, dt)
 
-    pool_shape = (n_pool_blocks, block_size, kv, hd)
-    if n_shards is not None:
-        pool_shape = (n_shards,) + pool_shape
-    blk = {}
-    for j in range(cfg.scan_period):
+        if cfg.mla:
+            return {"latent": mk(shard + (n_pool_blocks, block_size, 1, cfg.latent_dim), dtype)}
         if cfg.mixer_kind(j) == "attn":
-            blk[f"pos{j}"] = {
-                "k": mk(pool_shape, dtype),
-                "v": mk(pool_shape, dtype),
-            }
-        else:
-            blk[f"pos{j}"] = {
-                "conv": tuple(
-                    mk((n_slots, w - 1, c), dtype)
-                    for c in (cfg.d_inner, g * ds, g * ds)
-                ),
-                "ssm": mk((n_slots, h, hdm, ds), f32),
-            }
+            pool_shape = shard + (n_pool_blocks, block_size, kv, hd)
+            return {"k": mk(pool_shape, dtype), "v": mk(pool_shape, dtype)}
+        return {
+            "conv": tuple(
+                mk((n_slots, w - 1, c), dtype)
+                for c in (cfg.d_inner, g * ds, g * ds)
+            ),
+            "ssm": mk((n_slots, h, hdm, ds), f32),
+        }
+
+    blk = {f"pos{j}": position(cfg.n_blocks, j) for j in range(cfg.scan_period)}
+    if cfg.first_dense:
+        blk["lead"] = {"pos0": position(cfg.first_dense, 0)}
     return blk
 
 
 def paged_copy_block(cfg: ModelConfig, cache, src, dst):
-    """Copy one pool block's K/V across every attention layer — the
-    copy-on-write half of prefix sharing.  ``src`` holds a cached chunk
-    with refcount > 1 (or parked) whose tail the new request must
-    overwrite (full-prefix hit ending on a block boundary: the last
-    prompt token's K/V write lands in it); the engine allocates ``dst``
-    privately, copies, and repoints the request's table before the
+    """Copy one pool block across every pool leaf of every layer (K and V,
+    or the latent) — the copy-on-write half of prefix sharing.  ``src``
+    holds a cached chunk with refcount > 1 (or parked) whose tail the new
+    request must overwrite (full-prefix hit ending on a block boundary:
+    the last prompt token's K/V write lands in it); the engine allocates
+    ``dst`` privately, copies, and repoints the request's table before the
     row's first mixed-dispatch write runs.  Per-slot (SSM/conv) leaves
     have no block axis and pass through untouched.  Sharded pool leaves
     (6-D, see ``init_paged_cache``) copy between GLOBAL ids' (shard,
@@ -318,17 +370,17 @@ def paged_copy_block(cfg: ModelConfig, cache, src, dst):
             return leaf.at[:, s_dst, l_dst].set(leaf[:, s_src, l_src])
         return leaf.at[:, dst].set(jnp.take(leaf, src, axis=1))
 
-    out = {}
-    for key, sub in cache.items():
-        if "k" in sub:
-            out[key] = {kk: copy(leaf) for kk, leaf in sub.items()}
-        else:
-            out[key] = sub
-    return out
+    def walk(node):
+        if "conv" in node or "ssm" in node:  # per-slot state: no blocks
+            return node
+        return {k: walk(v) if isinstance(v, dict) else copy(v) for k, v in node.items()}
+
+    return walk(cache)
 
 
 def mixed_step(cfg: ModelConfig, pol: ShardingPolicy, params, tokens, cache,
-               block_tables, q_start, q_len, block_size: int, mesh=None):
+               block_tables, q_start, q_len, block_size: int, mesh=None, live=None,
+               live_max: int | None = None, counters: bool = False):
     """UNIFIED engine step: one layer-stack pass over a mixed batch of
     prefill chunks and decode rows against the paged cache — the ONE
     dispatch the unified serving path issues per engine step, replacing
@@ -343,18 +395,36 @@ def mixed_step(cfg: ModelConfig, pol: ShardingPolicy, params, tokens, cache,
     be chunked across steps at any boundary.  Returns ``(logits
     (B, W, V), cache)`` — the caller reads row ``b``'s next token off
     ``logits[b, q_len[b] - 1]`` when its prompt completes this step.
+
+    ``live`` ``(B, W)`` marks the lanes whose output is used (default:
+    lanes below ``q_len``); a held-expert layer computes and counts only
+    their pairs.  ``live_max``: a bound on the live lanes (the engine's
+    token budget), so held experts run on that many gathered lanes.
+    ``counters``: also return the routing counters
+    (``moe.ROUTING_COUNTERS``, summed over layers) as a third output.
     """
     b, w = tokens.shape
     h = L.embed_apply(cfg, pol, params["embed"], tokens)
     q_start = jnp.asarray(q_start, jnp.int32)
     q_len = jnp.asarray(q_len, jnp.int32)
     positions = q_start[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
-    h, cache, _ = _run_blocks(
+    rows = None
+    if cfg.experts_held:
+        if live is None:
+            live = jnp.arange(w)[None, :] < q_len[:, None]
+        if live_max is not None:
+            rows = MOE.live_rows(live, live_max)
+    h, cache, _, stats = _run_blocks(
         cfg, pol, params, h, positions, mode="mixed", cache=cache,
-        paged=(block_tables, block_size, q_len, mesh),
+        paged=(block_tables, block_size, q_len, mesh), live=live, rows=rows,
     )
     h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    return L.head_apply(cfg, pol, params, h), cache
+    logits = L.head_apply(cfg, pol, params, h)
+    return (logits, cache, _counters(stats)) if counters else (logits, cache)
+
+
+def _counters(stats):
+    return jnp.zeros((len(MOE.ROUTING_COUNTERS),), jnp.int32) if stats is None else stats
 
 
 def verify_step(cfg: ModelConfig, pol: ShardingPolicy, params, tokens, cache,
@@ -382,8 +452,16 @@ def verify_step(cfg: ModelConfig, pol: ShardingPolicy, params, tokens, cache,
     )
 
 
+def _contiguous_only(cfg: ModelConfig) -> None:
+    if cfg.mla or cfg.first_dense:
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention and leading dense layers have a paged cache only "
+            "(init_paged_cache)")
+
+
 def cache_pspecs(cfg: ModelConfig, pol: ShardingPolicy):
     """PartitionSpec tree matching init_cache structure."""
+    _contiguous_only(cfg)
     blk = {}
     for j in range(cfg.scan_period):
         if cfg.mixer_kind(j) == "attn":
@@ -408,28 +486,34 @@ def prefill(cfg: ModelConfig, pol: ShardingPolicy, params, batch, cache_len: int
     h = _embed_inputs(cfg, pol, params, batch)
     positions = jnp.broadcast_to(jnp.arange(s), tokens.shape)
     cache = init_cache(cfg, b, cache_len, dtype=jnp.dtype(cfg.dtype))
-    h, cache, _ = _run_blocks(cfg, pol, params, h, positions, mode="prefill", cache=cache)
+    h, cache, _, _ = _run_blocks(cfg, pol, params, h, positions, mode="prefill", cache=cache)
     h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps)
     return L.head_apply(cfg, pol, params, h), cache
 
 
 def decode_step(cfg: ModelConfig, pol: ShardingPolicy, params, cache, tokens, pos,
-                block_tables=None, block_size: int = 0, mesh=None):
+                block_tables=None, block_size: int = 0, mesh=None, live=None,
+                counters: bool = False):
     """One decode step.  tokens: (B,1) int32; pos: scalar int32 write
     position (attention sees [0..pos]) or (B,) per-row positions for
     ragged batches.  With ``block_tables`` (``(B, n_max_blocks)`` int32,
     requires per-row ``pos`` and a paged cache from ``init_paged_cache``)
     attention K/V reads/writes go through the block table instead of a
-    contiguous per-row stripe.  Returns (logits (B,1,V), cache)."""
+    contiguous per-row stripe.  Returns (logits (B,1,V), cache).
+
+    ``live`` ``(B,)`` marks the rows whose token is used (default: all);
+    ``counters`` returns the routing counters too, as in ``mixed_step``."""
     h = L.embed_apply(cfg, pol, params["embed"], tokens)
     pos = jnp.asarray(pos, jnp.int32)
     positions = jnp.broadcast_to(pos[:, None] if pos.ndim == 1 else pos, tokens.shape)
     paged = None if block_tables is None else (block_tables, block_size, mesh)
-    h, cache, _ = _run_blocks(
-        cfg, pol, params, h, positions, mode="decode", cache=cache, pos=pos, paged=paged
+    h, cache, _, stats = _run_blocks(
+        cfg, pol, params, h, positions, mode="decode", cache=cache, pos=pos, paged=paged,
+        live=None if live is None else live[:, None],
     )
     h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    return L.head_apply(cfg, pol, params, h), cache
+    logits = L.head_apply(cfg, pol, params, h)
+    return (logits, cache, _counters(stats)) if counters else (logits, cache)
 
 
 def generate(cfg, pol, params, batch, n_tokens: int, temperature: float = 0.0, key=None):

@@ -281,6 +281,105 @@ def moe_apply_a2a(cfg: ModelConfig, pol: ShardingPolicy, p, x):
 
 
 # ------------------------------------------------------------------ #
+# held experts: one chip's share of an expert-parallel layer, dropless
+# ------------------------------------------------------------------ #
+
+# routing counters a held-expert layer returns, summed over layers (and the
+# steps of a fused decode chunk): (token, expert) pairs routed to the held
+# experts, held experts with at least one pair, and the most pairs any one
+# held expert took
+ROUTING_COUNTERS = ("moe_pairs_held", "moe_experts_touched", "moe_max_expert_pairs")
+
+
+def held_moe_specs(cfg: ModelConfig) -> dict:
+    """The router at its full width (``n_experts``), the selection bias,
+    the ``experts_held`` experts this layer computes, and the shared
+    experts as one un-gated MLP."""
+    d, f, e = cfg.d_model, cfg.resolved_moe_d_ff, cfg.experts_held
+    s = {
+        "router": ParamSpec((d, cfg.n_experts), ("embed", None), "fan_in", fan_in_dims=(0,)),
+        "wg": ParamSpec((e, d, f), (None, "expert_in", "expert_mlp"), "fan_in", fan_in_dims=(1,)),
+        "wu": ParamSpec((e, d, f), (None, "expert_in", "expert_mlp"), "fan_in", fan_in_dims=(1,)),
+        "wd": ParamSpec((e, f, d), (None, "expert_mlp", "expert_in"), "fan_in", fan_in_dims=(1,)),
+        "select_bias": ParamSpec((cfg.n_experts,), (None,), "zeros"),
+    }
+    if cfg.n_shared_experts:
+        s["shared"] = mlp_specs(cfg, d_ff=cfg.n_shared_experts * f)
+    return s
+
+
+def route(cfg: ModelConfig, p, x2d):
+    """DeepSeek-V3 routing in float32 over all ``n_experts``: sigmoid
+    scores; the top ``moe_top_k`` picked by score plus the selection bias,
+    weighted by the unbiased scores, normalised, times ``moe_scale``.
+    x2d: (T, d) -> (weights (T, k) float32, ids (T, k))."""
+    f32 = jnp.float32
+    logits = jnp.dot(x2d.astype(f32), p["router"].astype(f32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(scores + p["select_bias"].astype(f32), cfg.moe_top_k)
+    w = jnp.take_along_axis(scores, ids, -1)
+    return w / (w.sum(-1, keepdims=True) + 1e-20) * cfg.moe_scale, ids
+
+
+def live_rows(live, live_max: int):
+    """``live_max`` token indices into the (B, S) bool ``live`` that hold
+    every live token, the live ones first (at most ``live_max`` are live);
+    None where ``live_max`` covers every token.  A step computes them once
+    for all its layers."""
+    t = live.size
+    if live_max >= t:
+        return None
+    return jnp.argsort(~live.reshape(t), stable=True)[:live_max]
+
+
+def held_moe_apply(cfg: ModelConfig, pol: ShardingPolicy, p, x, live=None, rows=None):
+    """One chip's share of an expert-parallel MoE layer: the router runs
+    over all experts; this layer computes only the part of the experts it
+    holds, ``[expert_offset, expert_offset + experts_held)``, with no
+    capacity (dropless: every token is computed by every held expert and
+    weighted by its routing weight, zero for an expert it did not pick,
+    so a token's output never depends on the other tokens of its step),
+    plus the shared experts.  The absent experts' part is left out, as
+    their chips would add it.
+
+    The held experts run as one batched matmul over the experts, in XLA:
+    ``jax.lax.ragged_dot`` lowers on the TPU to Mosaic kernels, which
+    would sit beside the attention kernel in each step program.
+
+    x: (B, S, d); ``live`` (B, S) bool marks the tokens whose output is
+    used (None: all): other tokens are neither computed by the held
+    experts nor counted.  ``rows``: indices into the ``B * S`` tokens that
+    hold every live one (``live_rows``), so the held experts run on those
+    gathered tokens instead of all of them.  Returns (out (B, S, d),
+    counters int32[3] (``ROUTING_COUNTERS``))."""
+    b, s, d = x.shape
+    e, t = cfg.experts_held, b * s
+    x2d = x.reshape(t, d)
+    w, ids = route(cfg, p, x2d)
+    local = ids - cfg.expert_offset
+    held = (local >= 0) & (local < e)
+    if live is not None:
+        held = held & live.reshape(t, 1)
+    # (T, e) routing weight of each token for each held expert
+    gate = jnp.sum(jnp.where(held[..., None], w[..., None], 0.0)
+                   * (local[..., None] == jnp.arange(e)), axis=1)
+    xs, gs = (x2d, gate) if rows is None else (x2d[rows], gate[rows])
+    dt = x.dtype
+    h = jax.nn.silu(jnp.einsum("td,edf->etf", xs, p["wg"].astype(dt))) * jnp.einsum(
+        "td,edf->etf", xs, p["wu"].astype(dt))
+    y = jnp.einsum("etf,efd->etd", h, p["wd"].astype(dt))
+    routed = jnp.einsum("etd,te->td", y.astype(jnp.float32), gs).astype(dt)
+    out = routed if rows is None else jnp.zeros_like(x2d).at[rows].set(routed)
+    out = out.reshape(b, s, d)
+    if cfg.n_shared_experts:
+        out = out + mlp_apply(cfg, pol, p["shared"], x)
+    counts = jnp.sum(held[..., None] & (local[..., None] == jnp.arange(e)), axis=(0, 1))
+    stats = jnp.stack([counts.sum(), (counts > 0).sum(), counts.max()]).astype(jnp.int32)
+    return pol.shard(out, "act_batch", "act_seq", "act_embed"), stats
+
+
+# ------------------------------------------------------------------ #
 # dense reference (oracle for tests)
 # ------------------------------------------------------------------ #
 

@@ -108,6 +108,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from repro.configs.base import ModelConfig
 from repro.data.tokenizer import EOS, PAD
 from repro.models import lm as LM
+from repro.models import moe as MOE
 from repro.runtime import tracing
 from repro.runtime.sharding import ShardingPolicy
 from repro.serving.kv_cache import (
@@ -359,6 +360,22 @@ class ServeEngine:
                 "requires an all-attention model: SSM/conv state folds the "
                 "whole sequence and cannot resume a chunked prompt"
             )
+        # latent attention and held experts run on the paged path alone;
+        # the sharded pool and the drafter have no latent or routed path,
+        # so they are refused rather than run wrong
+        if cfg.mla or cfg.experts_held or cfg.first_dense:
+            what = f"{cfg.name} (latent attention, held experts or leading dense layers)"
+            if not scfg.paged:
+                raise ValueError(f"{what} is served by the paged engine only: paged=True")
+            if scfg.shards is not None:
+                raise ValueError(f"{what}: the sharded pool (shards) has no latent-cache path")
+            if scfg.draft_k > 0:
+                raise ValueError(f"{what}: speculative decoding (draft_k) is not supported")
+        if scfg.draft_k > 0 and scfg.draft_config is not None and scfg.draft_config.mla:
+            raise ValueError("a latent-attention drafter is not supported")
+        # routing counters (moe.ROUTING_COUNTERS): trailing outputs of both
+        # step programs where the model holds experts
+        self._counters = cfg.experts_held > 0
         # paged -> unified: the mixed-dispatch loop is the only paged path
         self._unified = scfg.paged
         self._token_budget = (
@@ -563,9 +580,16 @@ class ServeEngine:
             rows = jnp.arange(b)
             q_start = jnp.where(is_decode, lengths + emitted - 1, q_start_h)
             tok = tok.at[:, 0].set(jnp.where(is_decode, cur, tok[:, 0]))
-            logits, cache = LM.mixed_step(
+            kw = {}
+            if self._counters:
+                # live lanes: within q_len, on a row that is not a done
+                # decode; they share the token budget, the row width
+                live = (jnp.arange(tok.shape[1])[None, :] < q_len[:, None]) & ~(
+                    is_decode & done)[:, None]
+                kw = dict(live=live, live_max=tok.shape[1], counters=True)
+            logits, cache, *routed = LM.mixed_step(
                 cfg, pol, params, tok, cache, tables, q_start, q_len, bs,
-                mesh=self._mesh,
+                mesh=self._mesh, **kw,
             )
             last = jnp.take_along_axis(
                 logits, jnp.maximum(q_len - 1, 0)[:, None, None], axis=1
@@ -588,7 +612,7 @@ class ServeEngine:
                 (nxt == EOS) | (b_new <= 1),
                 done | (emit_dec & ((nxt == EOS) | (emitted >= budget))),
             )
-            return cache, cur, lengths, emitted, done, budget, out
+            return (cache, cur, lengths, emitted, done, budget, out, *routed)
 
         kd = scfg.draft_k
 
@@ -746,13 +770,15 @@ class ServeEngine:
                     return (t < n_steps) & ~jnp.all(st[4])
 
                 def body(st):
-                    t, cache, cur, emitted, done, chunk = st
+                    t, cache, cur, emitted, done, chunk, routed = st
                     if paged:
-                        logits, cache = LM.decode_step(
+                        kw = dict(live=~done, counters=True) if self._counters else {}
+                        logits, cache, *step = LM.decode_step(
                             cfg, pol, params, cache, cur[:, None],
                             lengths + emitted - 1, block_tables=tables, block_size=bs,
-                            mesh=self._mesh,
+                            mesh=self._mesh, **kw,
                         )
+                        routed = [r + x for r, x in zip(routed, step)]
                     else:
                         logits, cache = LM.decode_step(
                             cfg, pol, params, cache, cur[:, None], lengths + emitted - 1
@@ -762,10 +788,13 @@ class ServeEngine:
                     chunk = chunk.at[:, t].set(nxt)
                     emitted = emitted + (~done)
                     done = done | (nxt == EOS) | (emitted >= budget)
-                    return (t + 1, cache, nxt, emitted, done, chunk)
+                    return (t + 1, cache, nxt, emitted, done, chunk, routed)
 
-                st = (jnp.int32(0), cache, cur, emitted, done, chunk)
-                _, cache, cur, emitted, done, chunk = jax.lax.while_loop(cond, body, st)
+                # routing counters summed over the chunk's steps (none
+                # for a model without held experts)
+                routed = [jnp.zeros((len(MOE.ROUTING_COUNTERS),), jnp.int32)] * self._counters
+                st = (jnp.int32(0), cache, cur, emitted, done, chunk, routed)
+                _, cache, cur, emitted, done, chunk, routed = jax.lax.while_loop(cond, body, st)
                 # ragged merge: row i's fresh tokens are chunk[i, :emitted-emitted0]
                 # landing at out[i, emitted0:emitted]; invalid lanes are clipped
                 # into the spare (t_cap) column, which holds no answer tokens
@@ -774,7 +803,7 @@ class ServeEngine:
                 valid = j[None, :] < (emitted - emitted0)[:, None]
                 keep = out[rows[:, None], idx]
                 out = out.at[rows[:, None], idx].set(jnp.where(valid, chunk, keep))
-                return cache, cur, emitted, done, out
+                return (cache, cur, emitted, done, out, *routed)
 
             return decode_chunk
 
@@ -1097,6 +1126,14 @@ class ServeEngine:
         own descriptors (fresh arrays each step, so not copied)."""
         return dict(lengths=lengths.copy(), emitted=emitted.copy(), done=done.copy(),
                     rids=[-1 if r is None else r.rid for r in slots], **descriptors)
+
+    @staticmethod
+    def _note_routing(disp, routed) -> None:
+        """Attach a dispatch's routing counters (its trailing output, read
+        in the read-back after ``emitted`` and ``done``, whose program has
+        then finished) to its ``engine.dispatch`` span, while recording."""
+        if routed and disp.recording:
+            disp.attrs.update(zip(MOE.ROUTING_COUNTERS, (int(v) for v in np.array(routed[0]))))
 
     def _dispatch_lifetime(self) -> dict:
         return {
@@ -1678,16 +1715,16 @@ class ServeEngine:
                                     jnp.asarray(is_dec), jnp.asarray(row_len_h),
                                     jnp.asarray(b_new_h), jnp.asarray(tables_h))
                             with tracing.span("engine.launch"):
-                                (self._cache, cur, lengths, emitted, done, budget, out) = (
-                                    self._mixed_rows(
-                                        self.params, self._cache, cur, lengths, emitted, done,
-                                        budget, out, *args,
-                                    )
+                                res = self._mixed_rows(
+                                    self.params, self._cache, cur, lengths, emitted, done,
+                                    budget, out, *args,
                                 )
+                                (self._cache, cur, lengths, emitted, done, budget, out) = res[:7]
                             self.mixed_dispatches += 1
                             steps += 1
                             with tracing.span("engine.readback"):
                                 em_h, dn_h = np.array(emitted), np.array(done)
+                                self._note_routing(disp, res[7:])
                         _stamp_first_tokens(slots, em_h, fills)
                     elif dec_rows:
                         # no fill in flight: fused multi-step decode, one dispatch
@@ -1718,14 +1755,16 @@ class ServeEngine:
                                 )
                             args = (jnp.int32(n), jnp.asarray(tables_h))
                             with tracing.span("engine.launch"):
-                                self._cache, cur, emitted, done, out = self._decode_chunk(
+                                res = self._decode_chunk(
                                     self.params, self._cache, cur, lengths, emitted, done,
                                     budget, out, *args,
                                 )
+                                self._cache, cur, emitted, done, out = res[:5]
                             self.decode_dispatches += 1
                             steps += 1
                             with tracing.span("engine.readback"):
                                 em_h, dn_h = np.array(emitted), np.array(done)
+                                self._note_routing(disp, res[5:])
                             if disp.recording:
                                 disp.attrs["emitted_after"] = em_h.copy()
                         _stamp_first_tokens(slots, em_h, fills)

@@ -105,6 +105,30 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_latent_attention_kernels_compile_for_v5e(one_chip, kind):
+    """Both attention kernels in Moonlight-16B-A3B's absorbed geometry (16
+    query heads over one 576-lane latent head whose first 512 lanes are
+    the values, scale 1/sqrt(192)) at the benchmark's 16 rows, 256 lanes
+    and 160-block tables: the chunked-prefill kernel's 4096 x 576 query
+    block must be cut into query tiles to fit its fast memory."""
+    from functools import partial
+
+    b, n_t, w = 16, 160, 256
+    n_pool = b * n_t + 1
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    pool = s((n_pool, BS, 1, 576), jnp.bfloat16)
+    if kind == "prefill":
+        fn = partial(mixed_prefill_attention_pallas, scale=192 ** -0.5, v_dim=512)
+        text = _compiled_text(lambda q, k, t, d: fn(q, k, None, t, d), s((b, w, 16, 576), jnp.bfloat16),
+                              pool, s((b, n_t), jnp.int32), s((b, 4), jnp.int32))
+    else:
+        fn = partial(paged_decode_attention_pallas, scale=192 ** -0.5, v_dim=512)
+        text = _compiled_text(lambda q, k, t, n: fn(q, k, None, t, n), s((b, 16, 576), jnp.bfloat16),
+                              pool, s((b, n_t), jnp.int32), s((b,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
 def test_retrieval_topk_bitonic_compiles_for_v5e(one_chip):
     q, n, d, k = 32, 16_384, 768, 32
     text = _compiled_text(

@@ -238,6 +238,35 @@ def test_mixed_prefill_attention_sweep(b, w, h, kv, dh, bs, n_t):
     assert (np.asarray(o_p)[lanes] == 0).all() and (np.asarray(o_r)[lanes] == 0).all()
 
 
+@pytest.mark.parametrize("budget", [None, 4096], ids=["one-tile", "query-tiles"])
+def test_latent_geometry_kernels_match_the_reference(monkeypatch, budget):
+    """Absorbed-MLA geometry in both kernels: one KV head whose first
+    ``v_dim`` lanes are the values (no V pool), a scale passed in; the
+    chunked-prefill kernel with its lanes in one query block, and cut into
+    query tiles on a grid axis of their own (a small fast-memory budget)."""
+    from repro.kernels.chunked_prefill import kernel as cp
+    from repro.kernels.chunked_prefill.ref import mixed_prefill_attention_ref
+    from repro.kernels.decode_attention.kernel import paged_decode_attention_pallas
+    from repro.kernels.decode_attention.ref import paged_decode_attention_ref
+
+    b, w, h, dh, dv, bs, n_t, scale = 4, 8, 4, 24, 16, 4, 5, 0.3
+    if budget is not None:
+        monkeypatch.setattr(cp, "VMEM_BUDGET", budget)
+        assert cp._q_tile_lanes(w, h, dh, dv, 4) == 2  # four query tiles
+    rng = np.random.default_rng(11)
+    q, kp, _, tables, desc = _rand_mixed_case(rng, b, w, h, 1, dh, bs, n_t)
+    o_p = cp.mixed_prefill_attention_pallas(q, kp, None, tables, desc, scale=scale, v_dim=dv)
+    o_r = mixed_prefill_attention_ref(q, kp, None, tables, desc, scale, dv)
+    assert o_p.shape == (b, w, h, dv)
+    assert_allclose(np.asarray(o_p), np.asarray(o_r), rtol=2e-5, atol=2e-5)
+    lanes = np.arange(w)[None, :] >= np.asarray(desc)[:, 2][:, None]
+    assert (np.asarray(o_p)[lanes] == 0).all()
+    lens = jnp.asarray(rng.integers(1, n_t * bs + 1, size=b), jnp.int32)
+    d_p = paged_decode_attention_pallas(q[:, 0], kp, None, tables, lens, scale=scale, v_dim=dv)
+    d_r = paged_decode_attention_ref(q[:, 0], kp, None, tables, lens, scale, dv)
+    assert_allclose(np.asarray(d_p), np.asarray(d_r), rtol=2e-5, atol=2e-5)
+
+
 @given(
     b=st.integers(1, 6),
     w=st.integers(1, 7),

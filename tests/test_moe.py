@@ -111,3 +111,72 @@ def test_router_gates_renormalized(key):
     gates, ids, probs = _route(cfg, p["router"], x)
     assert_allclose(np.asarray(gates.sum(-1)), np.ones(32), rtol=1e-5)
     assert (np.asarray(ids) < cfg.n_experts).all(), "padded experts must never be routed"
+
+
+# ------------------------------------------------------------------ #
+# held experts (DeepSeek-V3 routing, one chip's share)
+# ------------------------------------------------------------------ #
+
+
+def _held_cfg(**kw):
+    base = dict(name="held", family="moe", d_model=32, n_experts=16, moe_top_k=4, moe_d_ff=24,
+                d_ff=64, n_shared_experts=1, experts_held=4, expert_offset=4, moe_scale=2.5,
+                dtype="float32")
+    return ModelConfig(**{**base, **kw})
+
+
+def test_sigmoid_routing_picks_by_score_plus_bias_and_weighs_by_score():
+    """``moe.route`` against a selection written out by hand, with a bias
+    large enough to change some picks: experts are picked by sigmoid score
+    plus bias, weighted by the score alone, normalised, then scaled."""
+    from repro.models.moe import route
+
+    cfg = _held_cfg()
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(40, 32)).astype(np.float32)
+    router = rng.normal(scale=32 ** -0.5, size=(32, 16)).astype(np.float32)
+    bias = rng.normal(scale=0.3, size=(16,)).astype(np.float32)
+    w, ids = route(cfg, {"router": jnp.asarray(router), "select_bias": jnp.asarray(bias)},
+                   jnp.asarray(x))
+    scores = 1.0 / (1.0 + np.exp(-(x.astype(np.float64) @ router)))
+    want_ids = np.argsort(-(scores + bias), axis=1, kind="stable")[:, :4]
+    picked = np.take_along_axis(scores, want_ids, 1)
+    want_w = picked / (picked.sum(1, keepdims=True) + 1e-20) * 2.5
+    np.testing.assert_array_equal(np.sort(np.asarray(ids), 1), np.sort(want_ids, 1))
+    order = np.argsort(np.asarray(ids), 1)
+    np.testing.assert_allclose(np.take_along_axis(np.asarray(w), order, 1),
+                               np.take_along_axis(want_w, np.argsort(want_ids, 1), 1), rtol=1e-5)
+    by_score = np.argsort(-scores, axis=1, kind="stable")[:, :4]
+    assert (np.sort(by_score, 1) != np.sort(want_ids, 1)).any(), "the bias changed no pick"
+
+
+def test_a_held_layer_computes_only_its_experts_and_counts_their_pairs(key):
+    """The held layer's output is the shared expert plus, for each token,
+    the routing weight of each held expert it picked times that expert's
+    MLP; its counters count those (token, held expert) pairs."""
+    from repro.models.moe import held_moe_apply, held_moe_specs, route
+
+    cfg = _held_cfg()
+    p = init_params(held_moe_specs(cfg), key)
+    p["select_bias"] = jax.random.normal(jax.random.PRNGKey(1), (16,)) * 0.3
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 6, 32))
+    live = jnp.arange(12).reshape(2, 6) % 5 != 0
+    out, stats = held_moe_apply(cfg, POL, p, x, live)
+    w, ids = route(cfg, p, x.reshape(12, 32))
+    want = np.zeros((12, 32))
+    pairs = np.zeros(4, int)
+    for t in range(12):
+        for j in range(4):
+            e = int(ids[t, j]) - 4
+            if 0 <= e < 4 and bool(live.reshape(-1)[t]):
+                xe = np.asarray(x.reshape(12, 32)[t], np.float64)
+                h = xe @ np.asarray(p["wg"][e]) * (1 / (1 + np.exp(-(xe @ np.asarray(p["wg"][e])))))
+                h = h * (xe @ np.asarray(p["wu"][e]))
+                want[t] += float(w[t, j]) * (h @ np.asarray(p["wd"][e]))
+                pairs[e] += 1
+    sh = p["shared"]
+    xs = np.asarray(x.reshape(12, 32), np.float64)
+    g = xs @ np.asarray(sh["wg"])
+    want += (g / (1 + np.exp(-g)) * (xs @ np.asarray(sh["wu"]))) @ np.asarray(sh["wd"])
+    assert_allclose(np.asarray(out).reshape(12, 32), want, rtol=2e-4, atol=2e-5)
+    assert list(np.asarray(stats)) == [pairs.sum(), (pairs > 0).sum(), pairs.max()]

@@ -814,3 +814,25 @@ def test_spec_decode_config_validation(small_lm, monkeypatch):
             ServeConfig(draft_k=3, paged=True, draft_config=small, draft_params={},
                         max_prompt_len=8),
         )
+
+
+@pytest.mark.parametrize("option", ["contiguous", "shards", "draft_k"])
+def test_latent_attention_refuses_the_paths_it_has_no_cache_for(option):
+    """Latent attention with held experts serves on the paged engine only:
+    the contiguous engine, the sharded pool and the speculative drafter
+    have no latent path, so each is refused at construction, never run
+    wrong."""
+    from repro.configs.base import ModelConfig
+    from repro.models.params import init_params
+
+    cfg = ModelConfig(
+        name="tiny-mla", family="moe", n_layers=2, d_model=32, n_heads=2, n_kv_heads=1,
+        d_ff=64, vocab_size=64, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
+        v_head_dim=8, n_experts=8, moe_top_k=2, moe_d_ff=16, n_shared_experts=1,
+        experts_held=4, first_dense=1, dtype="float32")
+    params = init_params(LM.param_specs(cfg), jax.random.PRNGKey(0))
+    kw = {"contiguous": dict(paged=False), "shards": dict(paged=True, shards=1),
+          "draft_k": dict(paged=True, draft_k=2)}[option]
+    with pytest.raises(ValueError, match="tiny-mla"):
+        ServeEngine(cfg, POL, params, ServeConfig(max_batch=2, max_prompt_len=16,
+                                                  max_new_tokens=4, block_size=4, **kw))

@@ -236,3 +236,60 @@ def test_a_round_records_two_device_waits_per_provider(federation, concurrent):
     for name in ("fed.aggregate", "fed.prompt"):
         (r,) = by_name(recs.values(), name)
         assert r.attrs == {"round": orch.rounds}
+
+
+def test_each_dispatch_of_a_held_expert_model_carries_its_routing_counters(monkeypatch):
+    """A model whose MoE layers hold experts returns its routing counters
+    from both step programs; the engine attaches them to each
+    ``engine.dispatch`` span: the (token, held expert) pairs of the live
+    tokens, the held experts they touched and the most pairs one expert
+    took, each summed over the MoE layers (and a decode chunk's steps).
+    A dense model's dispatches carry none."""
+    import numpy as np
+
+    from repro.configs.base import ModelConfig
+    from repro.models import lm as LM
+    from repro.models.moe import ROUTING_COUNTERS
+    from repro.models.params import init_params
+    from repro.runtime.sharding import ShardingPolicy, base_rules
+    from repro.serving.engine import ServeConfig, ServeEngine
+
+    cfg = ModelConfig(
+        name="tiny-mla-moe", family="moe", n_layers=3, d_model=32, n_heads=2, n_kv_heads=1,
+        d_ff=64, vocab_size=128, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
+        v_head_dim=8, n_experts=8, moe_top_k=3, moe_d_ff=16, n_shared_experts=1,
+        experts_held=4, expert_offset=2, moe_scale=2.0, first_dense=1, dtype="float32")
+    params = init_params(LM.param_specs(cfg), jax.random.PRNGKey(0))
+    eng = ServeEngine(cfg, ShardingPolicy(rules=base_rules(False), mesh=None), params,
+                      ServeConfig(max_batch=2, max_prompt_len=16, max_new_tokens=6, paged=True,
+                                  block_size=4, token_budget=8))
+    sched = Scheduler()
+    sched.submit_many([np.arange(1, 11, dtype=np.int32), np.arange(20, 26, dtype=np.int32)], 6)
+    with tracing.recording():
+        eng.serve(sched)
+    disp = by_name(tracing.spans(), "engine.dispatch")
+    assert {d.attrs["kind"] for d in disp} == {"mixed", "decode"}
+    n_moe, k, held = 2, 3, 4
+    for d in disp:
+        a = d.attrs
+        pairs, touched, most = (a[n] for n in ROUTING_COUNTERS)
+        if a["kind"] == "mixed":
+            live = int(np.sum(np.where(a["is_decode"] & a["done"], 0, a["q_len"])))
+            steps = 1
+        else:
+            live = int(np.sum(a["emitted_after"] - a["emitted"]))
+            steps = a["n_steps"]
+        assert 0 <= pairs <= live * n_moe * k
+        assert touched <= held * n_moe * steps and most <= pairs <= most * max(touched, 1)
+        assert (pairs == 0) == (touched == 0)
+    assert sum(d.attrs["moe_pairs_held"] for d in disp) > 0
+    # a model without held experts returns no counters and its spans carry none
+    tracing.clear()
+    eng = make_fake_engine(monkeypatch, max_batch=2, max_new_tokens=3, paged=True,
+                           block_size=4, token_budget=4)
+    sched = Scheduler()
+    sched.submit_many([prompt_ending(30, length=6)], 3)
+    with tracing.recording():
+        eng.serve(sched)
+    dense = by_name(tracing.spans(), "engine.dispatch")
+    assert dense and not any(n in d.attrs for d in dense for n in ROUTING_COUNTERS)
