@@ -118,54 +118,120 @@ def decode_attention_pallas(
     return out.reshape(b, h, dh).astype(q.dtype)
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, *refs, bs, scale, n_t, dv):
-    """Grid (B, KV, n_max_blocks).  The scalar-prefetched block table
-    drives the K/V BlockSpec index maps, so pool block ``tbl[b, t]``
-    streams into VMEM for (batch b, logical block t) — the gather never
-    materializes in HBM.  Masking is positional: logical position
-    ``t * bs + lane`` is valid iff < lengths[b] — trash-backed lanes are
-    always past the length and contribute exp(-inf) = 0 exactly.
+# K bytes (the V tile is as large) one tile of live blocks streams: enough
+# that a tile's copies keep the DMA engines busy while the previous tile
+# computes, small enough that both buffers of K and V and the tile's f32
+# working copies stay far inside the scoped VMEM
+TILE_BYTES = 256 << 10
 
-    ``dv``: None when V has a pool of its own (``refs`` starts with its
-    block); else V is the first ``dv`` lanes of the K block, read once
-    (the latent head of absorbed MLA)."""
+
+def _tile_blocks(n_t: int, block_bytes: int) -> int:
+    """Live blocks per tile: the largest power of two whose K tile stays
+    within ``TILE_BYTES`` (at least one block), and no more than the
+    table holds."""
+    t = 1
+    while 2 * t * block_bytes <= TILE_BYTES and t < n_t:
+        t *= 2
+    return min(t, n_t)
+
+
+def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, *refs, bs, scale, tile, dv):
+    """Grid (B,): one step per row, all KV heads at once.  The pool stays
+    in HBM; a ``fori_loop`` walks the row's live blocks ``0 ..
+    cdiv(lengths[b], bs) - 1`` in tiles of ``tile`` blocks, each block one
+    DMA of its whole ``(KV, bs, lanes)`` slab (pool block ``tbl[b, j]``)
+    into a double-buffered VMEM tile, the next tile's copies started
+    before the current tile computes.  No block past the live ones is
+    copied, and a row of length 0 copies nothing and keeps ``acc = 0,
+    l = 0, m = NEG_INF``.  Masking is positional (``kpos < lengths[b]``):
+    the slots of a partial last tile hold blocks an earlier tile or row
+    copied, or the zeros the buffers start with (the grid runs in order,
+    so the first step clears them once), and contribute ``p == 0`` times
+    a finite value.
+
+    ``lanes`` (the buffers' last axis) is ``dh``, or on the chip ``dh``
+    rounded up to whole 128-lane tiles: the pool's HBM layout pads a
+    576-lane head to 640, and a DMA slice must cover whole tiles.  The
+    pad lanes are copied and never read.
+
+    ``dv``: None when V has a pool of its own (``refs`` starts with it);
+    else V is the first ``dv`` lanes of the K block, read once (the
+    latent head of absorbed MLA)."""
     if dv is None:
-        v_ref, o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
+        v_hbm, o_ref, m_ref, l_ref, k_buf, v_buf, sem = refs
+        pairs = ((k_hbm, k_buf), (v_hbm, v_buf))
     else:
-        o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
-    tj = pl.program_id(2)
+        o_ref, m_ref, l_ref, k_buf, sem = refs
+        pairs = ((k_hbm, k_buf),)
     bi = pl.program_id(0)
+    n_t = tbl_ref.shape[1]
+    kv, dh = q_ref.shape[1], q_ref.shape[3]
+    lanes = k_buf.shape[-1]
+    length = len_ref[bi]
+    n_live = jnp.minimum((length + bs - 1) // bs, n_t)
+    n_tiles = (n_live + tile - 1) // tile
 
-    @pl.when(tj == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    @pl.when(bi == 0)
+    def _():
+        for _, buf in pairs:
+            buf[...] = jnp.zeros_like(buf)
 
-    q = q_ref[0, 0].astype(jnp.float32)  # (G, dh)
-    k = k_ref[0, 0].astype(jnp.float32)  # (BS, dh)
-    v = v_ref[0, 0].astype(jnp.float32) if dv is None else k[:, :dv]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (G, BS)
-    kpos = tj * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(kpos < len_ref[bi], s, NEG_INF)
+    o_ref[...] = jnp.zeros_like(o_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
 
-    m_prev, l_prev = m_scr[...], l_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_prev * alpha + p.sum(-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(
-        p, v, preferred_element_type=jnp.float32
-    )
-    m_scr[...] = m_new
+    def tile_copies(i, slot, start):
+        """Start (or wait for) the DMA of each live block of tile ``i``."""
+        for u in range(tile):
+            j = i * tile + u
 
-    @pl.when(tj == n_t - 1)
-    def _finish():
-        o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
-        m_ref[0, 0] = m_scr[...]
-        l_ref[0, 0] = l_scr[...]
+            @pl.when(j < n_live)
+            def _():
+                blk = tbl_ref[bi, j]
+                for n, (src, dst) in enumerate(pairs):
+                    copy = pltpu.make_async_copy(
+                        src.at[blk, :, :, pl.ds(0, lanes)], dst.at[slot, :, u], sem.at[n, slot])
+                    if start:
+                        copy.start()
+                    else:
+                        copy.wait()
+
+    @pl.when(n_tiles > 0)
+    def _():
+        tile_copies(0, 0, True)
+
+    def body(i, carry):
+        slot = i % 2
+
+        @pl.when(i + 1 < n_tiles)
+        def _():
+            tile_copies(i + 1, 1 - slot, True)
+
+        tile_copies(i, slot, False)
+        kpos = i * tile * bs + jax.lax.broadcasted_iota(jnp.int32, (1, tile * bs), 1)
+        for h in range(kv):
+            q = q_ref[0, h].astype(jnp.float32)  # (G, dh)
+            k = k_buf[slot, h].reshape(tile * bs, lanes)[:, :dh].astype(jnp.float32)
+            if dv is None:
+                v = v_buf[slot, h].reshape(tile * bs, lanes)[:, :dh].astype(jnp.float32)
+            else:
+                v = k[:, :dv]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale  # (G, T*bs)
+            s = jnp.where(kpos < length, s, NEG_INF)
+            m_prev, l_prev = m_ref[0, h], l_ref[0, h]
+            m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[0, h] = l_prev * alpha + p.sum(-1, keepdims=True)
+            o_ref[0, h] = o_ref[0, h] * alpha + jax.lax.dot(
+                p, v, preferred_element_type=jnp.float32
+            )
+            m_ref[0, h] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, body, 0)
 
 
 def paged_decode_attention_pallas(
@@ -173,20 +239,22 @@ def paged_decode_attention_pallas(
     k_pool: jax.Array,  # (n_pool, bs, KV, dh) shared block pool
     v_pool: jax.Array | None,  # (n_pool, bs, KV, dh), or None: V is K's first v_dim lanes
     block_tables: jax.Array,  # (B, n_max_blocks) int32 pool ids per row
-    lengths: jax.Array,  # (B,) valid cache length per sequence
+    lengths: jax.Array,  # (B,) valid cache length per sequence; 0 for a row not served
     *,
     scale: float | None = None,  # None -> 1/sqrt(dh)
     v_dim: int | None = None,  # with v_pool None: V's lanes of the K head
 ):
-    """Flash-decode over a PAGED KV cache: same online-softmax stream as
-    ``decode_attention_pallas``, but the sequence axis is a block table —
-    the BlockSpec index map reads the scalar-prefetched table to pick
-    which pool block to DMA per grid step (the vLLM-style paged-attention
-    gather, done by the memory system instead of an HBM materialize).
+    """Flash-decode over a PAGED KV cache: the online-softmax stream of
+    ``decode_attention_pallas``, over the blocks a scalar-prefetched block
+    table names (the vLLM-style paged-attention gather, done by DMA
+    instead of an HBM materialize).  The work follows each row's live
+    blocks ``cdiv(lengths[b], bs)``, not the table's width: the kernel
+    copies those blocks alone, a tile at a time, and a row of length 0
+    costs one grid step and outputs zeros.
 
     With ``v_pool`` None (absorbed latent attention: one KV head whose
-    first ``v_dim`` lanes are the values) each grid step reads the block
-    once for both, and the output has ``v_dim`` lanes."""
+    first ``v_dim`` lanes are the values) each block is read once for
+    both, and the output has ``v_dim`` lanes."""
     b, h, dh = q.shape
     n_pool, bs, kv = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     n_t = block_tables.shape[1]
@@ -194,38 +262,37 @@ def paged_decode_attention_pallas(
     scale = 1.0 / np.sqrt(dh) if scale is None else scale
     shared = v_pool is None
     dv = v_dim if shared else dh
+    padded = -(-dh // 128) * 128
+    tile = _tile_blocks(n_t, kv * bs * padded * k_pool.dtype.itemsize)
 
     qg = q.reshape(b, kv, g, dh)
     if kv == 1:  # moving a unit axis: a reshape, no copy of the pool
         kt = k_pool.reshape(n_pool, 1, bs, dh)
     else:
         kt = k_pool.transpose(0, 2, 1, 3)  # (n_pool, KV, BS, dh)
-    kv_spec = pl.BlockSpec((1, 1, bs, dh), lambda bi, ki, tj, tbl, lens: (tbl[bi, tj], ki, 0, 0))
     operands = [qg, kt]
     if not shared:
         operands.append(v_pool.transpose(0, 2, 1, 3))
-    grid = (b, kv, n_t)
+    n_pools = len(operands) - 1
+    row = lambda bi, tbl, lens: (bi, 0, 0, 0)  # noqa: E731
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block_tables, lengths
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, g, dh), lambda bi, ki, tj, tbl, lens: (bi, ki, 0, 0)),
-        ] + [kv_spec] * (len(operands) - 1),
-        out_specs=[
-            pl.BlockSpec((1, 1, g, dv), lambda bi, ki, tj, tbl, lens: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda bi, ki, tj, tbl, lens: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda bi, ki, tj, tbl, lens: (bi, ki, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dv), jnp.float32),
-        ],
-    )
     def build(interpret):
+        lanes = dh if interpret else padded
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # block_tables, lengths
+            grid=(b,),
+            in_specs=[pl.BlockSpec((1, kv, g, dh), row)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * n_pools,
+            out_specs=[
+                pl.BlockSpec((1, kv, g, dv), row),
+                pl.BlockSpec((1, kv, g, 1), row),
+                pl.BlockSpec((1, kv, g, 1), row),
+            ],
+            scratch_shapes=[pltpu.VMEM((2, kv, tile, bs, lanes), k_pool.dtype)] * n_pools
+            + [pltpu.SemaphoreType.DMA((n_pools, 2))],
+        )
         return pl.pallas_call(
-            functools.partial(_paged_kernel, bs=bs, scale=scale, n_t=n_t,
+            functools.partial(_paged_kernel, bs=bs, scale=scale, tile=tile,
                               dv=dv if shared else None),
             grid_spec=grid_spec,
             out_shape=[

@@ -25,8 +25,9 @@ def decode_attention(q, k_cache, v_cache, lengths, use_pallas: bool = False):
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, use_pallas: bool = False,
                            scale: float | None = None, v_dim: int | None = None):
     """Single-token attention through a block table over a shared KV pool.
-    ``use_pallas=True`` streams pool blocks via scalar-prefetch index maps
-    (interpreted on the CPU); the default gathers in XLA.  ``v_pool`` None:
+    ``use_pallas=True`` copies each row's live pool blocks, named by the
+    scalar-prefetched table, into VMEM (interpreted on the CPU); the
+    default gathers in XLA.  ``v_pool`` None:
     the values are K's first ``v_dim`` lanes (a latent head); ``scale``
     None is 1/sqrt(dh)."""
     if use_pallas:

@@ -344,7 +344,8 @@ def _paged_attn_sharded(cfg: ModelConfig, q, k_new, v_new, k_pool, v_pool,
     return fn(q, k_pool, v_pool, k_new, v_new, block_tables, q_start, q_len)
 
 
-def attn_decode_paged(cfg: ModelConfig, pol: ShardingPolicy, p, x, k_pool, v_pool, pos, block_tables, block_size: int, mesh=None):
+def attn_decode_paged(cfg: ModelConfig, pol: ShardingPolicy, p, x, k_pool, v_pool, pos, block_tables, block_size: int, mesh=None,
+                      live=None):
     """Single-token decode against a PAGED KV cache.
 
     ``k_pool``/``v_pool``: ``(n_pool, block_size, KV, hd)`` shared block
@@ -364,11 +365,16 @@ def attn_decode_paged(cfg: ModelConfig, pol: ShardingPolicy, p, x, k_pool, v_poo
         block: their lanes are always behind the ``kpos <= pos`` mask, so
         whatever they hold contributes exactly 0 to softmax.
       * ``attn_impl="pallas"``: ``kernels/decode_attention``'s paged
-        flash-decode kernel — the scalar-prefetched block table drives
-        the K/V BlockSpec index maps, so the gather never materializes in
-        HBM (interpreted on the CPU; numerically equal to the XLA path
-        within flash-softmax reassociation tolerance, parity-tested in
-        tests/test_models.py).
+        flash-decode kernel — it DMAs each row's live pool blocks named by
+        the scalar-prefetched block table, so the gather never
+        materializes in HBM (interpreted on the CPU; numerically equal to
+        the XLA path within flash-softmax reassociation tolerance,
+        parity-tested in tests/test_models.py).
+
+    ``live`` ``(B,)`` marks the rows whose output is used (default: all).
+    The kernel sees length 0 for the others, so a finished row or a free
+    slot copies no block; its output is zeros.  Their K/V still lands at
+    ``pos``.  The XLA path reads every row's whole view either way.
     """
     b = x.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
@@ -397,8 +403,9 @@ def attn_decode_paged(cfg: ModelConfig, pol: ShardingPolicy, p, x, k_pool, v_poo
     if cfg.attn_impl == "pallas":
         from repro.kernels.decode_attention import ops as da_ops
 
+        lengths = pos + 1 if live is None else jnp.where(live, pos + 1, 0)
         out = da_ops.paged_decode_attention(
-            q[:, 0], k_pool, v_pool, block_tables, pos + 1, use_pallas=True,
+            q[:, 0], k_pool, v_pool, block_tables, lengths, use_pallas=True,
             scale=scale, v_dim=v_dim,
         )[:, None]  # (B,1,H,hd)
     else:

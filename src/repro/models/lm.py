@@ -89,7 +89,8 @@ def _run_position(cfg, pol, i, pp, h, positions, mode, cache_in, pos, paged=None
     """One layer (mixer + ffn).  cache_in: per-position cache pytree or None.
     ``ffn``: the layer's FFN kind (default ``cfg.ffn_kind(i)``); ``live``:
     the tokens whose output is used, gathered at ``rows``
-    (``moe.live_rows``), for a held-expert layer.
+    (``moe.live_rows``), for a held-expert layer; in paged ``decode`` mode
+    attention also skips the other rows' KV walk.
     ``paged``: None (contiguous cache) or ``(block_tables, block_size)``
     (+ ``q_len`` in ``mixed`` mode) — attention then reads/writes K/V
     through the block table (non-attention state is per-slot in both
@@ -112,7 +113,8 @@ def _run_position(cfg, pol, i, pp, h, positions, mode, cache_in, pos, paged=None
         if mode == "decode":
             tables, bs, mesh = paged
             o, lat, _ = L.attn_decode_paged(
-                cfg, pol, pp["attn"], x, cache_in["latent"], None, pos, tables, bs, mesh=mesh)
+                cfg, pol, pp["attn"], x, cache_in["latent"], None, pos, tables, bs, mesh=mesh,
+                live=None if live is None else live[:, 0])
         else:
             tables, bs, q_len, mesh = paged
             o, lat, _ = L.attn_mixed_paged(
@@ -124,7 +126,7 @@ def _run_position(cfg, pol, i, pp, h, positions, mode, cache_in, pos, paged=None
             tables, bs, mesh = paged
             o, k_c, v_c = L.attn_decode_paged(
                 cfg, pol, pp["attn"], x, cache_in["k"], cache_in["v"], pos, tables, bs,
-                mesh=mesh,
+                mesh=mesh, live=None if live is None else live[:, 0],
             )
             cache_out = {"k": k_c, "v": v_c}
         elif mode == "mixed":
@@ -501,8 +503,10 @@ def decode_step(cfg: ModelConfig, pol: ShardingPolicy, params, cache, tokens, po
     attention K/V reads/writes go through the block table instead of a
     contiguous per-row stripe.  Returns (logits (B,1,V), cache).
 
-    ``live`` ``(B,)`` marks the rows whose token is used (default: all);
-    ``counters`` returns the routing counters too, as in ``mixed_step``."""
+    ``live`` ``(B,)`` marks the rows whose token is used (default: all):
+    paged attention walks no KV block for the others, and held experts
+    skip them; ``counters`` returns the routing counters too, as in
+    ``mixed_step``."""
     h = L.embed_apply(cfg, pol, params["embed"], tokens)
     pos = jnp.asarray(pos, jnp.int32)
     positions = jnp.broadcast_to(pos[:, None] if pos.ndim == 1 else pos, tokens.shape)

@@ -772,11 +772,11 @@ class ServeEngine:
                 def body(st):
                     t, cache, cur, emitted, done, chunk, routed = st
                     if paged:
-                        kw = dict(live=~done, counters=True) if self._counters else {}
+                        # done rows and free slots walk no KV block
                         logits, cache, *step = LM.decode_step(
                             cfg, pol, params, cache, cur[:, None],
                             lengths + emitted - 1, block_tables=tables, block_size=bs,
-                            mesh=self._mesh, **kw,
+                            mesh=self._mesh, live=~done, counters=self._counters,
                         )
                         routed = [r + x for r, x in zip(routed, step)]
                     else:
@@ -1126,6 +1126,19 @@ class ServeEngine:
         own descriptors (fresh arrays each step, so not copied)."""
         return dict(lengths=lengths.copy(), emitted=emitted.copy(), done=done.copy(),
                     rids=[-1 if r is None else r.rid for r in slots], **descriptors)
+
+    @staticmethod
+    def _decode_kv_blocks(geom: dict, bs: int, grid_row: int) -> dict:
+        """A decode chunk's KV walk from its span's geometry: at step ``t``
+        a row not done reads ``cdiv(lengths + emitted + t, bs)`` blocks
+        (``kv_blocks_live``, over the steps each row emitted); the table's
+        whole width for every slot at every step the chunk ran is
+        ``kv_blocks_grid``, ``grid_row`` (``max_batch`` x table width) a
+        step."""
+        ran = np.where(geom["done"], 0, geom["emitted_after"] - geom["emitted"])
+        start = geom["lengths"] + geom["emitted"]
+        live = sum(int((-(-(s + np.arange(n)) // bs)).sum()) for s, n in zip(start, ran))
+        return dict(kv_blocks_live=live, kv_blocks_grid=int(ran.max(initial=0)) * grid_row)
 
     @staticmethod
     def _note_routing(disp, routed) -> None:
@@ -1767,6 +1780,8 @@ class ServeEngine:
                                 self._note_routing(disp, res[5:])
                             if disp.recording:
                                 disp.attrs["emitted_after"] = em_h.copy()
+                                disp.attrs.update(self._decode_kv_blocks(
+                                    disp.attrs, bs, B * tables_h.shape[1]))
                         _stamp_first_tokens(slots, em_h, fills)
 
                     retired = [

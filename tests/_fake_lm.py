@@ -38,7 +38,7 @@ class FakeLM:
 
     @staticmethod
     def decode_step(cfg, pol, params, cache, tokens, pos, block_tables=None, block_size=0,
-                    mesh=None):
+                    mesh=None, live=None, counters=False):
         return FakeLM._logits(tokens, FakeLM._offset(params)), cache
 
     @staticmethod

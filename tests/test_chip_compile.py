@@ -91,18 +91,26 @@ def test_mixed_prefill_kernel_compiles_for_v5e(one_chip, w, n_t):
     assert "tpu_custom_call" in text
 
 
-def test_paged_decode_kernel_compiles_for_v5e(one_chip):
-    b, n_t, n_pool = 8, 33, 8 * 33 + 1
+@pytest.mark.parametrize("h,n_t", [
+    pytest.param(H, 33, id="0.6b-33"),
+    pytest.param(H, 160, id="0.6b-160"),  # the benchmark cells' table width
+    pytest.param(32, 160, id="4b-160"),  # qwen3-4b: 32 query heads over the same 8 KV heads
+])
+def test_paged_decode_kernel_compiles_for_v5e(one_chip, h, n_t):
+    """The live-block walk (pool left in HBM, blocks copied by DMA into
+    double-buffered VMEM tiles) at qwen3-0.6b (G = 2) and qwen3-4b (G = 4)
+    widths: one Mosaic kernel in the program."""
+    b, n_pool = 8, 8 * n_t + 1
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     text = _compiled_text(
         paged_decode_attention_pallas,
-        s((b, H, DH), jnp.bfloat16),
+        s((b, h, DH), jnp.bfloat16),
         s((n_pool, BS, KV, DH), jnp.bfloat16),
         s((n_pool, BS, KV, DH), jnp.bfloat16),
         s((b, n_t), jnp.int32),
         s((b,), jnp.int32),
     )
-    assert "tpu_custom_call" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
 @pytest.mark.parametrize("kind", ["prefill", "decode"])
@@ -111,7 +119,8 @@ def test_latent_attention_kernels_compile_for_v5e(one_chip, kind):
     query heads over one 576-lane latent head whose first 512 lanes are
     the values, scale 1/sqrt(192)) at the benchmark's 16 rows, 256 lanes
     and 160-block tables: the chunked-prefill kernel's 4096 x 576 query
-    block must be cut into query tiles to fit its fast memory."""
+    block must be cut into query tiles to fit its fast memory, and the
+    decode kernel's block copies must cover the pool's 640 padded lanes."""
     from functools import partial
 
     b, n_t, w = 16, 160, 256
@@ -126,7 +135,7 @@ def test_latent_attention_kernels_compile_for_v5e(one_chip, kind):
         fn = partial(paged_decode_attention_pallas, scale=192 ** -0.5, v_dim=512)
         text = _compiled_text(lambda q, k, t, n: fn(q, k, None, t, n), s((b, 16, 576), jnp.bfloat16),
                               pool, s((b, n_t), jnp.int32), s((b,), jnp.int32))
-    assert "tpu_custom_call" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
 def test_retrieval_topk_bitonic_compiles_for_v5e(one_chip):

@@ -81,15 +81,29 @@ def test_decode_attention_sweep(b, s, h, kv, dh, bs):
 
 
 @pytest.mark.parametrize(
-    "b,h,kv,dh,bs,n_t", [(2, 8, 4, 32, 16, 4), (3, 4, 4, 16, 32, 2), (1, 16, 2, 64, 8, 8)]
+    "b,h,kv,dh,bs,n_t,tile_bytes,n_zero",
+    [
+        pytest.param(2, 8, 4, 32, 16, 4, None, 0, id="2-8-4-32-16-4"),
+        pytest.param(3, 4, 4, 16, 32, 2, None, 0, id="3-4-4-16-32-2"),
+        pytest.param(1, 16, 2, 64, 8, 8, None, 0, id="1-16-2-64-8-8"),
+        # G = 4, rows over up to five tiles of 2 blocks (the last one partial)
+        pytest.param(4, 8, 2, 32, 8, 9, 16 << 10, 0, id="g4-tiles"),
+        # two rows of length 0 among rows over several tiles
+        pytest.param(5, 8, 4, 16, 4, 7, 16 << 10, 2, id="zero-rows-tiles"),
+    ],
 )
-def test_paged_decode_attention_sweep(b, h, kv, dh, bs, n_t):
-    """Paged flash-decode: block-table gather through scalar-prefetch
-    index maps must match (a) the gather reference and (b) the dense
-    kernel run on each row's materialized contiguous view."""
-    from repro.kernels.decode_attention.kernel import paged_decode_attention_pallas
+def test_paged_decode_attention_sweep(monkeypatch, b, h, kv, dh, bs, n_t, tile_bytes, n_zero):
+    """Paged flash-decode: block-table gather through the live-block walk
+    must match (a) the gather reference and (b) the dense kernel run on
+    each row's materialized contiguous view; a row of length 0 outputs
+    zeros (the reference averages its masked values, which callers
+    discard)."""
+    from repro.kernels.decode_attention import kernel as da
     from repro.kernels.decode_attention.ref import paged_decode_attention_ref
 
+    if tile_bytes is not None:
+        monkeypatch.setattr(da, "TILE_BYTES", tile_bytes)
+        assert da._tile_blocks(n_t, kv * bs * 128 * 4) == 2  # f32 blocks, lanes padded
     n_pool = b * n_t + 1  # +1 pool block left dangling (never referenced)
     kk = jax.random.PRNGKey(b * h + n_t)
     q = jax.random.normal(kk, (b, h, dh))
@@ -98,15 +112,57 @@ def test_paged_decode_attention_sweep(b, h, kv, dh, bs, n_t):
     rng = np.random.default_rng(0)
     # disjoint, shuffled tables: physical order != logical order
     tables = jnp.asarray(rng.permutation(n_pool - 1)[: b * n_t].reshape(b, n_t), jnp.int32)
-    lens = jnp.asarray(rng.integers(1, n_t * bs + 1, size=b), jnp.int32)
-    o_p = paged_decode_attention_pallas(q, kp, vp, tables, lens)
+    lens = rng.integers(1, n_t * bs + 1, size=b)
+    lens[:n_zero] = 0
+    lens = jnp.asarray(lens, jnp.int32)
+    live = np.asarray(lens) > 0
+    o_p = da.paged_decode_attention_pallas(q, kp, vp, tables, lens)
     o_r = paged_decode_attention_ref(q, kp, vp, tables, lens)
-    assert_allclose(np.asarray(o_p), np.asarray(o_r, np.float32), rtol=2e-5, atol=2e-5)
+    assert_allclose(np.asarray(o_p)[live], np.asarray(o_r, np.float32)[live], rtol=2e-5, atol=2e-5)
+    assert (np.asarray(o_p)[~live] == 0).all()
     # dense equivalence: gather each row's blocks into a contiguous cache
     kc = np.asarray(kp)[np.asarray(tables)].reshape(b, n_t * bs, kv, dh)
     vc = np.asarray(vp)[np.asarray(tables)].reshape(b, n_t * bs, kv, dh)
     o_d = decode_attention_ref(q, jnp.asarray(kc), jnp.asarray(vc), lens)
     assert_allclose(np.asarray(o_r), np.asarray(o_d, np.float32), rtol=0, atol=0)
+
+
+@pytest.mark.parametrize("geometry", ["gqa", "latent"])
+def test_paged_decode_kernel_never_reads_past_live_blocks(monkeypatch, geometry):
+    """Every pool block the table maps past a row's live blocks (``0 ..
+    cdiv(len, bs) - 1``), and every block of a length-0 row, is NaN: a
+    kernel that copied one into a tile it computes would carry the NaN
+    into the output (``0 * NaN`` in ``p @ v``), so the live rows' outputs
+    must be bit-identical to the clean run.  Rows end mid-block, exactly
+    on a block edge, at the table's end, and at length 0, over a 7-block
+    table walked in tiles of 2 blocks (the last tile partial)."""
+    from repro.kernels.decode_attention import kernel as da
+
+    b, bs, n_t = 5, 4, 7
+    h, kv, dh, dv, scale = (8, 2, 32, None, None) if geometry == "gqa" else (4, 1, 24, 16, 0.3)
+    monkeypatch.setattr(da, "TILE_BYTES", 2 * kv * bs * 128 * 4)
+    assert da._tile_blocks(n_t, kv * bs * 128 * 4) == 2
+    kk = jax.random.PRNGKey(17)
+    n_pool = b * n_t + 1
+    q = jax.random.normal(kk, (b, h, dh))
+    kp = jax.random.normal(jax.random.fold_in(kk, 1), (n_pool, bs, kv, dh))
+    vp = None if dv else jax.random.normal(jax.random.fold_in(kk, 2), (n_pool, bs, kv, dh))
+    tb = np.random.default_rng(5).permutation(n_pool)[: b * n_t].reshape(b, n_t)
+    tables = jnp.asarray(tb, jnp.int32)
+    ln = np.array([3 * bs, n_t * bs, 0, 2 * bs + 3, 1])  # edge, full, empty, mid-block, one key
+    lens = jnp.asarray(ln, jnp.int32)
+    dead = np.concatenate([tb[i, -(-n // bs):] for i, n in enumerate(ln)])
+    assert dead.size == n_t * b - (3 + n_t + 0 + 3 + 1)
+
+    def run(k, v):
+        return np.asarray(da.paged_decode_attention_pallas(q, k, v, tables, lens,
+                                                            scale=scale, v_dim=dv))
+
+    clean = run(kp, vp)
+    poisoned = run(kp.at[dead].set(np.nan), None if vp is None else vp.at[dead].set(np.nan))
+    assert np.isfinite(clean).all()
+    assert np.array_equal(clean, poisoned)
+    assert (clean[2] == 0).all()
 
 
 def test_paged_decode_trash_blocks_never_leak():

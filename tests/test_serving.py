@@ -469,6 +469,41 @@ def test_unified_lifts_dense_pipeline_restrictions(small_lm):
         assert hit_eng.prefix_hits >= 2
 
 
+def test_a_decode_chunk_walks_no_kv_for_done_rows_or_free_slots(small_lm, monkeypatch):
+    """Pallas paged decode: a row whose ``done`` is set (its budget spent
+    mid-chunk) and the free slots reach the decode kernel with length 0,
+    so they copy no KV block, while the live rows get the tokens of the
+    XLA path, which reads every row's whole view."""
+    from repro.kernels.decode_attention import ops as da_ops
+
+    cfg, params = small_lm
+    kw = dict(max_batch=4, max_prompt_len=16, max_new_tokens=8, sched_chunk=4,
+              paged=True, block_size=4)
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(8, cfg.vocab_size, size=n).astype(np.int32) for n in (9, 6)]
+    budgets = [8, 2]
+    want = ServeEngine(cfg, POL, params, ServeConfig(**kw)).serve_prompts(prompts, budgets)
+
+    seen = []  # the lengths of every decode-kernel call: one per layer and step
+    kernel = da_ops.paged_decode_attention
+
+    def spy(q, k_pool, v_pool, tables, lengths, **k):
+        jax.debug.callback(lambda ln: seen.append(np.array(ln)), lengths)
+        return kernel(q, k_pool, v_pool, tables, lengths, **k)
+
+    monkeypatch.setattr(da_ops, "paged_decode_attention", spy)
+    eng = ServeEngine(cfg.with_overrides(attn_impl="pallas"), POL, params, ServeConfig(**kw))
+    got = eng.serve_prompts(prompts, budgets)
+    for i, (w, g) in enumerate(zip(want, got)):
+        assert np.array_equal(w, g), f"prompt {i}: pallas {list(g)} != xla {list(w)}"
+    assert eng.decode_dispatches > 0 and seen
+    seen = np.stack(seen)
+    assert (seen[:, 2:] == 0).all(), "free slots must walk no block"
+    # slot 1 finishes on its chunk's first step; slot 0 decodes on alone
+    assert ((seen[:, 0] > 0) & (seen[:, 1] == 0)).any()
+    assert ((seen[:, 0] > 0) & (seen[:, 1] > 0)).any()
+
+
 def test_unified_dispatch_count_o1_per_step(monkeypatch):
     """Regression: the unified engine must issue exactly ONE device
     dispatch per engine step — no per-admit prefill calls (k admits in a

@@ -196,6 +196,40 @@ def test_a_mixed_dispatch_counts_the_kv_blocks_its_attention_walks(monkeypatch):
     assert all(a["kv_blocks_grid"] == 3 * 3 for a in mixed)  # 12 cache tokens / 4
 
 
+def test_a_decode_dispatch_counts_the_kv_blocks_its_attention_walks(monkeypatch):
+    """``kv_blocks_live`` sums, over a decode chunk's steps and the rows
+    not done at each, ``cdiv(lengths + emitted + t, block_size)`` (the
+    length the kernel sees at step ``t``); ``kv_blocks_grid`` is the steps
+    the chunk ran x ``max_batch`` x the table width.  Both follow from the
+    geometry the span records."""
+    eng = make_fake_engine(monkeypatch, max_batch=3, max_new_tokens=6, paged=True,
+                           block_size=4, token_budget=8, sched_chunk=4)
+    sched = Scheduler()
+    sched.submit_many([prompt_ending(30, length=7), prompt_ending(40, length=5)], [6, 2])
+    with tracing.recording():
+        eng.serve(sched)
+    decode = [r.attrs for r in by_name(tracing.spans(), "engine.dispatch")
+              if r.attrs["kind"] == "decode"]
+    assert decode
+    n_t = 4  # 7 + 6 cache tokens, padded to 16, over blocks of 4
+    for a in decode:
+        ran = [0 if dn else int(e1 - e0)
+               for dn, e0, e1 in zip(a["done"], a["emitted"], a["emitted_after"])]
+        live = sum(-(-(ln + e0 + t) // 4)
+                   for ln, e0, n in zip(a["lengths"], a["emitted"], ran) for t in range(n))
+        assert a["kv_blocks_live"] == live
+        assert a["kv_blocks_grid"] == max(ran) * 3 * n_t
+    # the one decode chunk: slot 0 (prompt 7, 2 tokens out) decodes 4 steps at
+    # lengths 9-12 (3 blocks each); slot 1 (prompt 5) its last token at
+    # length 6 (2 blocks), then is done; slot 2 is free
+    (d,) = decode
+    assert list(d["lengths"]) == [7, 5, 1] and list(d["emitted"]) == [2, 1, 1]
+    assert list(d["done"]) == [False, False, True]
+    assert list(d["emitted_after"]) == [6, 2, 1]
+    assert d["kv_blocks_live"] == 4 * 3 + 2
+    assert d["kv_blocks_grid"] == 4 * 3 * n_t
+
+
 @pytest.fixture(scope="module")
 def federation():
     from repro.core.pipeline import CFedRAGConfig, CFedRAGSystem
